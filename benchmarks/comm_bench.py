@@ -46,7 +46,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_config, reduced_config  # noqa: E402
@@ -62,6 +61,7 @@ from repro.distributed.comm_plan import (  # noqa: E402
     save_plan,
 )
 from repro.launch.hlo_analysis import analyze_hlo, comm_report  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.training.specs import param_specs  # noqa: E402
 
@@ -77,7 +77,7 @@ def parse_mesh(spec, n_dev):
     """``--mesh 2x4`` -> a named 2-axis mesh; default: all devices on
     one "data" axis (the old single-axis behavior)."""
     if not spec:
-        return jax.make_mesh((n_dev,), ("data",))
+        return make_mesh((n_dev,), ("data",))
     dims = tuple(int(x) for x in spec.split("x"))
     if math.prod(dims) != n_dev:
         raise SystemExit(f"--mesh {spec}: product {math.prod(dims)} != "
@@ -86,7 +86,7 @@ def parse_mesh(spec, n_dev):
     if len(dims) > 2:
         raise SystemExit(f"--mesh {spec}: at most 2 axes supported")
     axes = ("data",) if len(dims) == 1 else ("data", "model")
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def grad_tree(arch: str, full: bool, reduced: bool = False):
@@ -148,12 +148,12 @@ def build_sync(mode, mesh, grads, wire, bucket_bytes, hier_split=1):
                 gathered = [jax.lax.all_gather(s, dp_axes, tiled=True)
                             for s in shards]
             return unpack(gathered, plan, use_kernel=False,
-                          denom=jax.lax.psum(1, dp_axes))
+                          denom=jax.lax.axis_size(dp_axes))
         return compressed_psum(g, dp_axes, wire, mean=True)
 
     specs = jax.tree.map(lambda _: P(), grads)
-    fn = shard_map(local, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(specs,),
+                       out_specs=specs, check_vma=False)
     return jax.jit(fn)
 
 

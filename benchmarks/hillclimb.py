@@ -24,14 +24,14 @@ def diagnose(arch, shape, variant="baseline", top=14, out_dir="results/hillclimb
     from repro.configs import get_config, shapes_for
     from repro.launch import hlo_analysis as H
     from repro.launch.dryrun import lower_cell
-    from repro.launch.mesh import cell_parallel, make_production_mesh
+    from repro.launch.mesh import (cell_parallel, make_mesh,
+                                   make_production_mesh)
 
     mesh = make_production_mesh()
     kwargs = {}
     if os.environ.get("HILLCLIMB_MESH"):
-        import jax as _jax
         d, m = (int(x) for x in os.environ["HILLCLIMB_MESH"].split("x"))
-        mesh = _jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     if attention_impl:
         kwargs["attention_impl"] = attention_impl
     if moe_group:

@@ -45,6 +45,7 @@ from repro.configs import (  # noqa: E402
     reduced_config,
 )
 from repro.data.pipeline import DataPipeline  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.launch.train import build_train_setup  # noqa: E402
 
 MODES = {
@@ -67,7 +68,7 @@ def bench_mode(name: str, kw: dict, *, arch: str, global_batch: int,
                bucket_bytes: int, iters: int, warmup: int,
                data_workers: int) -> dict:
     cfg = reduced_config(get_config(arch))
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     model, state, step, data, put, _ = build_train_setup(
         cfg, global_batch=global_batch, seq_len=16,
         opt_cfg=OptimizerConfig(), steps_per_epoch=10, mesh=mesh,

@@ -404,9 +404,10 @@ def run_audit(model: str = "resnet50", modes: Optional[List[str]] = None,
     if bucket_bytes is None:
         # small enough that even the reduced param stream cuts >1 bucket
         bucket_bytes = 4 * 2 ** 20 if full else 8 * 2 ** 10
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8, 1), ("data", "model"))
     # hierarchical cells need a genuinely 2-axis DP mesh (outer x inner)
-    hier_mesh = jax.make_mesh(HIER_MESH_SHAPE, ("data", "model"))
+    hier_mesh = make_mesh(HIER_MESH_SHAPE, ("data", "model"))
 
     cells = []
     for mode in modes:

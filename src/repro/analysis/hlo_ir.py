@@ -98,9 +98,10 @@ def type_shape(type_str: str) -> Tuple[str, Tuple[int, ...]]:
     return m.group(1), dims
 
 
+# result types may carry TPU tiled layouts, e.g. f32[8,128]{1,0:T(8,128)}
 _OP_RE = re.compile(
     r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*"
-    r"((?:\([^()]*\))|(?:[\w\[\],{}.]+))\s+"
+    r"((?:\((?:[^()]|\([^()]*\))*\))|(?:[\w\[\],{}.:()]+))\s+"
     r"([\w\-]+)\((.*)$"
 )
 

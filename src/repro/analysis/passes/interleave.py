@@ -83,7 +83,7 @@ def interleave_report(text: str,
     evidence: in the non-overlapped step every gradient all-reduce
     depends on the full backward and must sit after the last backward
     convolution/dot; in the overlapped step (DESIGN.md §8) the
-    ``optimization_barrier`` pipeline pins each bucket's collective
+    data edges of ``core.compression.after`` pin each bucket's collective
     between backward segments, so substantial conv/dot compute appears
     between the first and last collective and after the first one.
 

@@ -54,12 +54,19 @@ def bn_batch_stats(x: jax.Array,
 
 def bn_apply_stats(x: jax.Array, mean, var, scale, bias,
                    eps: float = 1e-5) -> jax.Array:
-    """Normalize in the compute dtype; only the per-channel scale/offset
-    are folded in fp32 (one bf16 stream instead of two fp32 streams —
-    EXPERIMENTS.md §Perf resnet iteration)."""
+    """Normalize with the per-channel scale/offset folded in fp32: one
+    compute-dtype read and one write per element (the upcast fuses into
+    the elementwise op — EXPERIMENTS.md §Perf resnet iteration).
+
+    The arithmetic is fp32 even for a bf16 ``x``, so autodiff's
+    per-channel reductions for ``scale``/``bias`` (and ``mean``/``var``)
+    accumulate in fp32. Multiplying in bf16 instead made those
+    reductions run in bf16 under XLA 0.9, several percent off for a few
+    hundred elements (tests/test_fused_bn.py bf16 parity matrix, checked
+    against an f64 reference)."""
     inv = (jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32))
     off = bias.astype(jnp.float32) - mean * inv
-    return (x * inv.astype(x.dtype) + off.astype(x.dtype)).astype(x.dtype)
+    return (x.astype(jnp.float32) * inv + off).astype(x.dtype)
 
 
 def _is_stat(node) -> bool:

@@ -25,7 +25,7 @@ in ``distributed/bucketing.py``).
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -72,40 +72,70 @@ def parse_compression(spec: Optional[str]) -> Tuple[Optional[str], bool]:
     return wire, bucketed
 
 
+def after(x: jax.Array, dep: jax.Array) -> jax.Array:
+    """``x`` with every value unchanged, made data-dependent on ``dep``.
+
+    Subtracting +0.0 is the identity for every ``x`` (-0.0 included),
+    and the +0.0 is computed from one element of ``dep``, so whatever
+    consumes the result cannot run before ``dep`` exists. This is how
+    the explicit sync modes order their per-leaf and per-bucket
+    collectives: an ``optimization_barrier`` alone no longer does it,
+    because XLA's CPU pipeline expands barriers before its all-reduce
+    combiner, which then merges every independent collective into
+    one."""
+    d = dep.reshape(-1)[0].astype(jnp.float32)
+    zero = jnp.where(jnp.isfinite(d), jnp.abs(d) * 0.0, 0.0)
+    return x - zero.astype(x.dtype)
+
+
+def chained(parts: Sequence[jax.Array], collective) -> List[jax.Array]:
+    """``collective`` applied part by part (leaves or buckets), each
+    input ``after`` the previous result: exactly one collective per
+    part, in order, which no combiner can merge."""
+    out: List[jax.Array] = []
+    for x in parts:
+        out.append(collective(after(x, out[-1]) if out else x))
+    return out
+
+
 def compressed_psum(grads: PyTree, axis_names: Sequence[str],
                     wire: Optional[str] = "bf16",
                     mean: bool = True) -> PyTree:
     """Paper-faithful compressed all-reduce (shard_map mode).
 
-    Cast each gradient leaf to the wire dtype, psum over the data axes,
-    cast back to the accumulation dtype. ``mean=True`` divides by the
-    number of workers (the paper averages per-worker gradients).
+    Cast each gradient leaf to the wire dtype, psum over the data axes
+    (one collective per leaf, ``chained`` in leaf order), cast back to
+    the accumulation dtype. ``mean=True`` divides by the number of
+    workers (the paper averages per-worker gradients).
     """
     wdt = _wire(wire)
-    # static axis-size product; psum of a python constant folds at trace
-    # time (no collective is emitted), unlike lax.axis_size which does
-    # not exist on this jax version
-    n = jax.lax.psum(1, tuple(axis_names))
+    n = jax.lax.axis_size(tuple(axis_names))  # static worker count
 
-    def sync(g):
-        acc_dtype = g.dtype
-        if wdt is not None:
-            g = g.astype(wdt)
-        g = jax.lax.psum(g, tuple(axis_names))
-        g = g.astype(acc_dtype)
-        return g / n if mean else g
-
-    return jax.tree.map(sync, grads)
+    leaves, treedef = jax.tree.flatten(grads)
+    wired = [g.astype(wdt) if wdt is not None else g for g in leaves]
+    summed = chained(wired, lambda g: jax.lax.psum(g, tuple(axis_names)))
+    out = []
+    for g, s in zip(leaves, summed):
+        s = s.astype(g.dtype)
+        out.append(s / n if mean else s)
+    return jax.tree.unflatten(treedef, out)
 
 
 def simulate_wire_cast(grads: PyTree, wire: Optional[str] = "bf16") -> PyTree:
     """GSPMD mode: round-trip gradients through the wire dtype so the
-    numerics of compressed communication are applied; XLA's collective
-    then carries the low-precision value when it can sink the cast."""
+    numerics of compressed communication are applied.
+
+    The round trip sits behind an ``optimization_barrier``: XLA's TPU
+    compiler otherwise folds ``convert(convert(g, f16), f32)`` to ``g``
+    (excess precision is allowed by default), and the GSPMD step then
+    trained on f32 gradients while the shard_map step rounded them to
+    f16 — measured on a TPU v5e, PERF.md."""
     wdt = _wire(wire)
     if wdt is None:
         return grads
-    return jax.tree.map(lambda g: g.astype(wdt).astype(g.dtype), grads)
+    return jax.tree.map(
+        lambda g: jax.lax.optimization_barrier(g.astype(wdt)).astype(
+            g.dtype), grads)
 
 
 # ---------------------------------------------------------------------------

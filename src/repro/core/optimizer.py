@@ -35,13 +35,33 @@ def alpha_rmsprop(h: HybridHyper):
     return (1.0 - h.alpha_sgd) * h.eta_rmsprop / h.eta
 
 
+def _decayed(g, theta32, weight_decay):
+    """L2-in-gradient (Goyal baseline): ``g + wd * theta``.
+    ``weight_decay`` is a python float (per-leaf tree update; 0.0 skips
+    the term) or an array shaped like ``g`` (packed stream, 0.0 on the
+    no-decay leaves).
+
+    The decay product is rounded on its own. Left as a bare multiply,
+    the compiler may contract it with the add into one FMA, or contract
+    the gradient's 1/n mean multiply instead, depending on how each
+    layout happened to fuse — and the per-leaf tree, full-stream and
+    ZeRO-shard updates then differ in the last bit. The select passes
+    every value through (NaN stays NaN) but is not a multiply, so this
+    side offers nothing to contract; the 1/n side is exact for
+    power-of-two worker counts (tests/test_zero.py bitwise parity)."""
+    if isinstance(weight_decay, (int, float)) and not weight_decay:
+        return g
+    decay = weight_decay * theta32
+    return g + jnp.where(jnp.isnan(decay), jnp.nan, decay)
+
+
 def hybrid_update(g, theta, delta, m, h: HybridHyper,
-                  weight_decay: float = 0.0) -> Tuple:
-    """One leaf update. Returns (theta', delta', m'). fp32 math."""
+                  weight_decay=0.0) -> Tuple:
+    """One update over a leaf or a flat stream. Returns
+    (theta', delta', m'). fp32 math."""
     g = g.astype(jnp.float32)
     theta32 = theta.astype(jnp.float32)
-    if weight_decay:
-        g = g + weight_decay * theta32  # L2-in-gradient (Goyal baseline)
+    g = _decayed(g, theta32, weight_decay)
     m_new = h.mu2 * m + (1.0 - h.mu2) * jnp.square(g)
     coef = h.alpha_sgd + alpha_rmsprop(h) / (jnp.sqrt(m_new) + h.eps)
     delta_new = h.mu1 * delta - coef * g
@@ -50,12 +70,11 @@ def hybrid_update(g, theta, delta, m, h: HybridHyper,
 
 
 def momentum_sgd_update(g, theta, delta, h: HybridHyper,
-                        weight_decay: float = 0.0) -> Tuple:
+                        weight_decay=0.0) -> Tuple:
     """Goyal et al. baseline: the a_sgd = 1 special case, no m state."""
     g = g.astype(jnp.float32)
     theta32 = theta.astype(jnp.float32)
-    if weight_decay:
-        g = g + weight_decay * theta32
+    g = _decayed(g, theta32, weight_decay)
     delta_new = h.mu1 * delta - g
     theta_new = theta32 + h.eta * delta_new
     return theta_new.astype(theta.dtype), delta_new

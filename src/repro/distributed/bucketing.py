@@ -33,7 +33,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.compression import _wire, apply_error_feedback
+from repro.core.compression import _wire, apply_error_feedback, chained
 
 PyTree = Any
 
@@ -277,13 +277,14 @@ def bucketed_psum(grads: PyTree, axis_names: Sequence[str],
     if plan is None:
         align = hierarchy.n_workers if hierarchy is not None else 1
         plan = plan_buckets(grads, bucket_bytes, wire, align=align)
-    # psum of a python constant folds to the static axis-size product
-    n = jax.lax.psum(1, tuple(axis_names))
+    n = jax.lax.axis_size(tuple(axis_names))  # static worker count
     buckets = pack(grads, plan, use_kernel=use_kernel)
     if hierarchy is not None:
-        synced = [hierarchical_psum(b, hierarchy) for b in buckets]
+        synced = chained(buckets,
+                         lambda b: hierarchical_psum(b, hierarchy))
     else:
-        synced = [jax.lax.psum(b, tuple(axis_names)) for b in buckets]
+        synced = chained(buckets,
+                         lambda b: jax.lax.psum(b, tuple(axis_names)))
     return unpack(synced, plan, use_kernel=use_kernel,
                   denom=n if mean else None, with_sq_norm=with_sq_norm)
 

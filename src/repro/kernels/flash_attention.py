@@ -79,7 +79,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def flash_attention(q, k, v, *, causal=True, window=None,
-                    block_q=128, block_k=128, interpret=True):
+                    block_q=128, block_k=128, interpret):
     """q: (B, Sq, Hq, Dh); k/v: (B, Sk, Hkv, Dh) -> (B, Sq, Hq, Dh).
 
     Layout inside the kernel is (B, H, S, Dh) for MXU-friendly tiles.

@@ -29,10 +29,11 @@ to autodiff of the pmean'd jnp path (DESIGN.md §10).
 
 The pure-jnp path in ``core/batchnorm.py`` stays the oracle; the
 analytic reference fwd/bwd lives in ``kernels/ref.py``. On TPU the
-kernels run compiled with ``ROW_BLOCK`` tiles; on CPU they run in
-interpret mode with a single whole-array block (grid tracing cost, not
-VMEM, is the binding constraint there) — how this container validates
-them (tests/test_fused_bn.py).
+kernels run compiled with ``ROW_BLOCK`` tiles of a (rows, C) view; on
+CPU they run in interpret mode with a single whole-array block in the
+activation's own shape (grid tracing cost, not VMEM, is the binding
+constraint there; see ``_view``) — how the tests validate them
+(tests/test_fused_bn.py).
 """
 from __future__ import annotations
 
@@ -51,7 +52,7 @@ ROW_BLOCK = 256  # rows x C fp32 tiles; C <= 2048 keeps ~2 MB in VMEM
 # ---------------------------------------------------------------------------
 
 
-def _stats_kernel(x_ref, s_ref, q_ref, *, n_rows, rb):
+def _stats_kernel(x_ref, s_ref, q_ref, *, n_rows, rb, masked):
     """One-pass per-channel sum and **centered** second moment
     M2 = sum((x - mu)^2), fp32 accumulation: each block computes its sum
     and its moment about the block mean, and grid steps merge via
@@ -59,18 +60,24 @@ def _stats_kernel(x_ref, s_ref, q_ref, *, n_rows, rb):
     (init on step 0). Centered-per-block keeps the E[x^2] - mu^2
     cancellation out of the kernel — the same fix bn_batch_stats got —
     at zero extra HBM traffic (the block is already VMEM-resident).
-    Zero-padded tail rows (block index >= ``n_rows``) are masked out of
-    both moments."""
+    With ``masked`` (the view has a zero-padded tail), rows at index
+    >= ``n_rows`` are masked out of both moments. Every axis but the
+    last (channel) one is reduced, so the kernel serves both the
+    (rows, C) tiles and the whole-array N-D block (``_view``)."""
     i = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)
-    ridx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) + i * rb
-    valid = ridx < n_rows
-    x = jnp.where(valid, x, 0.0)
+    axes = tuple(range(x.ndim - 1))
+    if masked:
+        ridx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) + i * rb
+        valid = ridx < n_rows
+        x = jnp.where(valid, x, 0.0)
     bn = jnp.clip(n_rows - i * rb, 1, rb).astype(jnp.float32)
-    bsum = jnp.sum(x, axis=0, keepdims=True)
+    bsum = jnp.sum(x, axis=axes, keepdims=True)
     bmean = bsum / bn
-    d = jnp.where(valid, x - bmean, 0.0)
-    bm2 = jnp.sum(d * d, axis=0, keepdims=True)
+    d = x - bmean
+    if masked:
+        d = jnp.where(valid, d, 0.0)
+    bm2 = jnp.sum(d * d, axis=axes, keepdims=True)
 
     @pl.when(i == 0)
     def _init():
@@ -113,10 +120,11 @@ def _bwd_sums_kernel(dy_ref, x_ref, y_ref, mu_ref, rstd_ref,
     i = pl.program_id(0)
     dy = dy_ref[...].astype(jnp.float32)
     if relu:
-        dy = jnp.where(y_ref[...] > 0, dy, 0.0)
+        dy = jnp.where(y_ref[...].astype(jnp.float32) > 0, dy, 0.0)
     xhat = (x_ref[...].astype(jnp.float32) - mu_ref[...]) * rstd_ref[...]
-    s1 = jnp.sum(dy, axis=0, keepdims=True)
-    s2 = jnp.sum(dy * xhat, axis=0, keepdims=True)
+    axes = tuple(range(dy.ndim - 1))
+    s1 = jnp.sum(dy, axis=axes, keepdims=True)
+    s2 = jnp.sum(dy * xhat, axis=axes, keepdims=True)
 
     @pl.when(i == 0)
     def _init():
@@ -137,7 +145,7 @@ def _bwd_dx_kernel(dy_ref, x_ref, y_ref, mu_ref, rstd_ref,
     The eval (given-stats) variant is the same kernel with B = C = 0."""
     dy = dy_ref[...].astype(jnp.float32)
     if relu:
-        dy = jnp.where(y_ref[...] > 0, dy, 0.0)
+        dy = jnp.where(y_ref[...].astype(jnp.float32) > 0, dy, 0.0)
     xhat = (x_ref[...].astype(jnp.float32) - mu_ref[...]) * rstd_ref[...]
     dx = a_ref[...] * dy - b_ref[...] - xhat * c_ref[...]
     dx_ref[...] = dx.astype(dx_ref.dtype)
@@ -149,7 +157,7 @@ def _bwd_dx_res_kernel(dy_ref, x_ref, y_ref, mu_ref, rstd_ref,
     no extra pass for the shortcut branch."""
     dy = dy_ref[...].astype(jnp.float32)
     if relu:
-        dy = jnp.where(y_ref[...] > 0, dy, 0.0)
+        dy = jnp.where(y_ref[...].astype(jnp.float32) > 0, dy, 0.0)
     xhat = (x_ref[...].astype(jnp.float32) - mu_ref[...]) * rstd_ref[...]
     dx = a_ref[...] * dy - b_ref[...] - xhat * c_ref[...]
     dx_ref[...] = dx.astype(dx_ref.dtype)
@@ -157,45 +165,68 @@ def _bwd_dx_res_kernel(dy_ref, x_ref, y_ref, mu_ref, rstd_ref,
 
 
 # ---------------------------------------------------------------------------
-# (rows, C) plumbing
+# operand views and pallas_call plumbing
 # ---------------------------------------------------------------------------
 
 
-def _row_view(x, row_block: Optional[int]) -> Tuple[jax.Array, int, int]:
-    """(..., C) -> zero-padded (rows_padded, C); returns (x2d, rows, rb).
+def _view(x, row_block: Optional[int]) -> Tuple[jax.Array, int, int]:
+    """The kernels' view of an (..., C) activation; returns
+    (view, rows, rb) with ``rows`` the true (..., ) element count.
 
-    ``row_block=None`` (the default off-TPU) uses one whole-array block:
-    in interpret mode the grid is traced in Python, so a single block is
-    both the cheapest and the exact semantics; compiled TPU runs block
-    by ``ROW_BLOCK`` to bound VMEM."""
+    Compiled on TPU (``row_block`` set): the zero-padded (rows_p, C)
+    view, cut into ``row_block``-row tiles to bound VMEM.
+    ``row_block=None`` (interpret mode, off-TPU): the activation itself
+    as one whole-array block, in its own N-D shape. The grid is then a
+    single step (interpret mode traces it in Python) and the block
+    reductions run over the activation's own axes — the same reduce the
+    jnp path emits, which XLA's CPU compiler fuses into the producing
+    elementwise op, where a (rows, C) column reduce is split into a
+    reduce-window that materialises its input first
+    (tests/test_fused_bn.py::test_fusion_report_real_lowering)."""
     c = x.shape[-1]
     rows = x.size // c
+    if row_block is None:
+        return x, rows, rows
     x2 = x.reshape(rows, c)
-    rb = rows if row_block is None else min(row_block, rows)
+    rb = min(row_block, rows)
     pad = (-rows) % rb
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     return x2, rows, rb
 
 
-def _blocked(kernel, n_in: int, n_out: int, rb: int, rows_p: int, c: int,
-             out_dtypes, interpret: bool, per_channel_in: int = 0):
-    """pallas_call builder: ``n_in`` (rows, C) streams + ``per_channel_in``
-    (1, C) broadcast inputs -> ``n_out`` outputs ((1, C) accumulators for
-    reduction kernels, (rows, C) streams otherwise)."""
-    grid = (rows_p // rb,)
-    row_spec = pl.BlockSpec((rb, c), lambda i: (i, 0))
-    ch_spec = pl.BlockSpec((1, c), lambda i: (0, 0))
+def _unview(v, shape, rows: int):
+    """Inverse of ``_view`` for an activation-shaped output."""
+    return v.reshape(-1, shape[-1])[:rows].reshape(shape)
+
+
+def _blocked(kernel, n_in: int, n_out: int, view, rb: int, out_dtypes,
+             interpret: bool, per_channel_in: int = 0):
+    """pallas_call factory over ``view`` (from ``_view``): ``n_in``
+    activation-shaped streams + ``per_channel_in`` per-channel
+    broadcast inputs (``_ch``) -> ``n_out`` outputs (per-channel
+    accumulators for reduction kernels, activation-shaped streams
+    otherwise). Blocks are ``rb`` rows of a (rows, C) view, or the whole
+    array when ``view`` is the N-D activation itself."""
+    shape = view.shape
+    nd = len(shape)
+    lead = rb if nd == 2 else shape[0]
+    grid = (shape[0] // lead,)
+    rest = (0,) * (nd - 1)
+    row_spec = pl.BlockSpec((lead,) + tuple(shape[1:]),
+                            lambda i: (i,) + rest)
+    ch_shape = (1,) * (nd - 1) + (shape[-1],)
+    ch_spec = pl.BlockSpec(ch_shape, lambda i: (0,) * nd)
     in_specs = [row_spec] * n_in + [ch_spec] * per_channel_in
     out_specs = []
     out_shape = []
-    for dt, shape in out_dtypes:
-        if shape == "channel":
+    for dt, kind in out_dtypes:
+        if kind == "channel":
             out_specs.append(ch_spec)
-            out_shape.append(jax.ShapeDtypeStruct((1, c), dt))
+            out_shape.append(jax.ShapeDtypeStruct(ch_shape, dt))
         else:
             out_specs.append(row_spec)
-            out_shape.append(jax.ShapeDtypeStruct((rows_p, c), dt))
+            out_shape.append(jax.ShapeDtypeStruct(shape, dt))
     if n_out == 1:
         out_specs, out_shape = out_specs[0], out_shape[0]
     return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
@@ -203,18 +234,21 @@ def _blocked(kernel, n_in: int, n_out: int, rb: int, rows_p: int, c: int,
                           interpret=interpret)
 
 
-def _moments_2d(x2, n_rows, rb, interpret):
+def _moments(xv, n_rows, rb, interpret):
     """Returns per-channel (sum, centered M2) over the ``n_rows`` true
-    rows of the padded (rows_p, C) view."""
-    rows_p, c = x2.shape
-    kernel = functools.partial(_stats_kernel, n_rows=n_rows, rb=rb)
-    s, q = _blocked(kernel, 1, 2, rb, rows_p, c,
-                    [(jnp.float32, "channel")] * 2, interpret)(x2)
-    return s[0], q[0]
+    rows of the view ``xv``."""
+    rows_p = xv.size // xv.shape[-1]
+    kernel = functools.partial(_stats_kernel, n_rows=n_rows, rb=rb,
+                               masked=rows_p != n_rows)
+    s, q = _blocked(kernel, 1, 2, xv, rb,
+                    [(jnp.float32, "channel")] * 2, interpret)(xv)
+    return s.reshape(-1), q.reshape(-1)
 
 
-def _ch(v, c):
-    return jnp.asarray(v, jnp.float32).reshape(1, c)
+def _ch(v, view):
+    """A per-channel vector shaped to broadcast against ``view``."""
+    return jnp.asarray(v, jnp.float32).reshape(
+        (1,) * (view.ndim - 1) + (view.shape[-1],))
 
 
 # ---------------------------------------------------------------------------
@@ -231,9 +265,8 @@ def _train_fn(relu: bool, has_res: bool, res_dtype: Optional[str],
     (y, mean, var)."""
 
     def fwd_impl(x, scale, bias, residual):
-        c = x.shape[-1]
-        x2, rows, rb = _row_view(x, row_block)
-        s, m2 = _moments_2d(x2, rows, rb, interpret)
+        x2, rows, rb = _view(x, row_block)
+        s, m2 = _moments(x2, rows, rb, interpret)
         m = float(rows)
         mean = s / m
         var = m2 / m  # centered: >= 0 by construction
@@ -248,32 +281,31 @@ def _train_fn(relu: bool, has_res: bool, res_dtype: Optional[str],
         a = rstd * scale.astype(jnp.float32)
         off = bias.astype(jnp.float32) - mean * a
         if has_res:
-            r2, _, _ = _row_view(residual, row_block)
+            r2, _, _ = _view(residual, row_block)
             y2 = _blocked(functools.partial(_apply_res_kernel, relu=relu),
-                          2, 1, rb, x2.shape[0], c, [(x.dtype, "rows")],
+                          2, 1, x2, rb, [(x.dtype, "rows")],
                           interpret, per_channel_in=2)(
-                x2, r2, _ch(a, c), _ch(off, c))
+                x2, r2, _ch(a, x2), _ch(off, x2))
         else:
             y2 = _blocked(functools.partial(_apply_kernel, relu=relu),
-                          1, 1, rb, x2.shape[0], c, [(x.dtype, "rows")],
+                          1, 1, x2, rb, [(x.dtype, "rows")],
                           interpret, per_channel_in=2)(
-                x2, _ch(a, c), _ch(off, c))
-        y = y2[:rows].reshape(x.shape)
+                x2, _ch(a, x2), _ch(off, x2))
+        y = _unview(y2, x.shape, rows)
         return y, mean, var
 
     def bwd_impl(res, cts):
         x, y, mean, var, scale = res
         dy, dmean_ct, dvar_ct = cts
-        c = x.shape[-1]
-        x2, rows, rb = _row_view(x, row_block)
-        y2, _, _ = _row_view(y, row_block)
-        dy2, _, _ = _row_view(dy, row_block)
+        x2, rows, rb = _view(x, row_block)
+        y2, _, _ = _view(y, row_block)
+        dy2, _, _ = _view(dy, row_block)
         rstd = jax.lax.rsqrt(var + eps)
         s1, s2 = _blocked(
-            functools.partial(_bwd_sums_kernel, relu=relu), 3, 2, rb,
-            x2.shape[0], c, [(jnp.float32, "channel")] * 2, interpret,
-            per_channel_in=2)(dy2, x2, y2, _ch(mean, c), _ch(rstd, c))
-        s1, s2 = s1[0], s2[0]
+            functools.partial(_bwd_sums_kernel, relu=relu), 3, 2, x2, rb,
+            [(jnp.float32, "channel")] * 2, interpret,
+            per_channel_in=2)(dy2, x2, y2, _ch(mean, x2), _ch(rstd, x2))
+        s1, s2 = s1.reshape(-1), s2.reshape(-1)
         m = float(rows)
         if axes:
             # global sums / count: the textbook sync-BN backward, equal
@@ -295,22 +327,20 @@ def _train_fn(relu: bool, has_res: bool, res_dtype: Optional[str],
         # 2*dv*(x-mu)/M = (2*dv/(M*rstd)) * x_hat
         b_coef = a_coef * s1g / big_m - dm / big_m
         c_coef = a_coef * s2g / big_m - 2.0 * dv / (big_m * rstd)
-        ch = [_ch(mean, c), _ch(rstd, c), _ch(a_coef, c), _ch(b_coef, c),
-              _ch(c_coef, c)]
+        ch = [_ch(v, x2) for v in (mean, rstd, a_coef, b_coef, c_coef)]
         if has_res:
             dx2, dr2 = _blocked(
                 functools.partial(_bwd_dx_res_kernel, relu=relu), 3, 2,
-                rb, x2.shape[0], c,
-                [(x.dtype, "rows"), (jnp.dtype(res_dtype), "rows")],
+                x2, rb, [(x.dtype, "rows"), (jnp.dtype(res_dtype), "rows")],
                 interpret, per_channel_in=5)(dy2, x2, y2, *ch)
-            dres = dr2[:rows].reshape(x.shape)
+            dres = _unview(dr2, x.shape, rows)
         else:
             dx2 = _blocked(
-                functools.partial(_bwd_dx_kernel, relu=relu), 3, 1, rb,
-                x2.shape[0], c, [(x.dtype, "rows")], interpret,
+                functools.partial(_bwd_dx_kernel, relu=relu), 3, 1, x2,
+                rb, [(x.dtype, "rows")], interpret,
                 per_channel_in=5)(dy2, x2, y2, *ch)
             dres = None
-        dx = dx2[:rows].reshape(x.shape)
+        dx = _unview(dx2, x.shape, rows)
         dscale = s2.astype(scale.dtype)  # local sums: DP sync happens
         dbias = s1.astype(scale.dtype)   # downstream, like any leaf grad
         return dx, dscale, dbias, dres
@@ -352,56 +382,52 @@ def _apply_fn(relu: bool, has_res: bool, res_dtype: Optional[str],
     for mean/var so the op stays differentiable everywhere."""
 
     def fwd_impl(x, mean, var, scale, bias, residual):
-        c = x.shape[-1]
-        x2, rows, rb = _row_view(x, row_block)
+        x2, rows, rb = _view(x, row_block)
         rstd = jax.lax.rsqrt(var.astype(jnp.float32) + eps)
         a = rstd * scale.astype(jnp.float32)
         off = bias.astype(jnp.float32) - mean.astype(jnp.float32) * a
         if has_res:
-            r2, _, _ = _row_view(residual, row_block)
+            r2, _, _ = _view(residual, row_block)
             y2 = _blocked(functools.partial(_apply_res_kernel, relu=relu),
-                          2, 1, rb, x2.shape[0], c, [(x.dtype, "rows")],
+                          2, 1, x2, rb, [(x.dtype, "rows")],
                           interpret, per_channel_in=2)(
-                x2, r2, _ch(a, c), _ch(off, c))
+                x2, r2, _ch(a, x2), _ch(off, x2))
         else:
             y2 = _blocked(functools.partial(_apply_kernel, relu=relu),
-                          1, 1, rb, x2.shape[0], c, [(x.dtype, "rows")],
+                          1, 1, x2, rb, [(x.dtype, "rows")],
                           interpret, per_channel_in=2)(
-                x2, _ch(a, c), _ch(off, c))
-        return y2[:rows].reshape(x.shape)
+                x2, _ch(a, x2), _ch(off, x2))
+        return _unview(y2, x.shape, rows)
 
     def bwd_impl(res, dy):
         x, y, mean, var, scale = res
-        c = x.shape[-1]
-        x2, rows, rb = _row_view(x, row_block)
-        y2, _, _ = _row_view(y, row_block)
-        dy2, _, _ = _row_view(dy, row_block)
+        x2, rows, rb = _view(x, row_block)
+        y2, _, _ = _view(y, row_block)
+        dy2, _, _ = _view(dy, row_block)
         mean32 = mean.astype(jnp.float32)
         rstd = jax.lax.rsqrt(var.astype(jnp.float32) + eps)
         s1, s2 = _blocked(
-            functools.partial(_bwd_sums_kernel, relu=relu), 3, 2, rb,
-            x2.shape[0], c, [(jnp.float32, "channel")] * 2, interpret,
-            per_channel_in=2)(dy2, x2, y2, _ch(mean32, c), _ch(rstd, c))
-        s1, s2 = s1[0], s2[0]
+            functools.partial(_bwd_sums_kernel, relu=relu), 3, 2, x2, rb,
+            [(jnp.float32, "channel")] * 2, interpret,
+            per_channel_in=2)(dy2, x2, y2, _ch(mean32, x2), _ch(rstd, x2))
+        s1, s2 = s1.reshape(-1), s2.reshape(-1)
         g32 = scale.astype(jnp.float32)
         a_coef = g32 * rstd
         zero = jnp.zeros_like(a_coef)
-        ch = [_ch(mean32, c), _ch(rstd, c), _ch(a_coef, c), _ch(zero, c),
-              _ch(zero, c)]
+        ch = [_ch(v, x2) for v in (mean32, rstd, a_coef, zero, zero)]
         if has_res:
             dx2, dr2 = _blocked(
                 functools.partial(_bwd_dx_res_kernel, relu=relu), 3, 2,
-                rb, x2.shape[0], c,
-                [(x.dtype, "rows"), (jnp.dtype(res_dtype), "rows")],
+                x2, rb, [(x.dtype, "rows"), (jnp.dtype(res_dtype), "rows")],
                 interpret, per_channel_in=5)(dy2, x2, y2, *ch)
-            dres = dr2[:rows].reshape(x.shape)
+            dres = _unview(dr2, x.shape, rows)
         else:
             dx2 = _blocked(
-                functools.partial(_bwd_dx_kernel, relu=relu), 3, 1, rb,
-                x2.shape[0], c, [(x.dtype, "rows")], interpret,
+                functools.partial(_bwd_dx_kernel, relu=relu), 3, 1, x2,
+                rb, [(x.dtype, "rows")], interpret,
                 per_channel_in=5)(dy2, x2, y2, *ch)
             dres = None
-        dx = dx2[:rows].reshape(x.shape)
+        dx = _unview(dx2, x.shape, rows)
         dmean = (-a_coef * s1).astype(mean.dtype)
         dvar = (-0.5 * g32 * jnp.square(rstd) * s2).astype(var.dtype)
         dscale = s2.astype(scale.dtype)
@@ -438,7 +464,7 @@ def _apply_fn(relu: bool, has_res: bool, res_dtype: Optional[str],
 def fused_bn_train(x, scale, bias, *, residual=None, relu: bool = False,
                    eps: float = 1e-5,
                    cross_replica: Optional[Sequence[str]] = None,
-                   interpret: bool = True,
+                   interpret: bool,
                    row_block: Optional[int] = None):
     """Train-mode fused BN: (y, mean, var) from one stats pass + one
     normalize/epilogue pass; fused custom-VJP backward (module
@@ -460,7 +486,7 @@ def fused_bn_train(x, scale, bias, *, residual=None, relu: bool = False,
 
 def fused_bn_apply(x, mean, var, scale, bias, *, residual=None,
                    relu: bool = False, eps: float = 1e-5,
-                   interpret: bool = True,
+                   interpret: bool,
                    row_block: Optional[int] = None):
     """Given-stats fused BN (eval / finalized statistics): normalize +
     epilogue in one pass, differentiable (full mean/var cotangents)."""

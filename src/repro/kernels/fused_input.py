@@ -23,11 +23,21 @@ stream in ops.input_augment_params — identical whether evaluated eagerly
 on host (the AugmentedSource reference path) or traced on device, so the
 fused and host paths consume bitwise-identical parameters and the
 transform itself is the only difference under test. Grid is one program
-per sample; each program reads its (4,) parameter row.
+per sample; each program reads its (4,) parameter row from SMEM (scalar
+prefetch).
 
-CPU caveat: on this container the kernel runs in Pallas interpret mode
-(ops._interpret()); on TPU it compiles. Parity vs ref.input_forward is
-pinned in tests/test_fused_input.py for {f32, bf16} x {train, eval}.
+Layout: each image is viewed lane-dense as (H, W*C), so a block is one
+whole image with no 3-wide lane dimension (which the TPU pads to 128
+lanes, 40x the VMEM). The flip and the cyclic shifts are then row and
+column permutations, applied as two exact 0/1 permutation matmuls
+(``Ph @ x @ Pw``, f32 at HIGHEST precision: every output is one input
+times 1.0 plus zeros), and the per-channel normalize uses (1, W*C) lane
+patterns of mean and 1/std.
+
+Off-TPU the kernel runs in Pallas interpret mode (``ops._interpret``).
+Parity vs ref.input_forward is pinned in tests/test_fused_input.py for
+{f32, bf16} x {train, eval}; tests/test_tpu_compile.py compiles it for
+v5e at (32, 224, 224, 3).
 """
 from __future__ import annotations
 
@@ -36,64 +46,91 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _train_kernel(params_ref, mean_ref, inv_ref, x_ref, o_ref):
-    x = x_ref[0].astype(jnp.float32)  # (H, W, C)
-    p = params_ref[0]  # (4,) int32: [flip, dy, dx, reserved]
-    flipped = jnp.where(p[0] > 0, x[:, ::-1, :], x)
-    shifted = jnp.roll(flipped, (p[1], p[2]), axis=(0, 1))
-    y = (shifted - mean_ref[0]) * inv_ref[0]
-    o_ref[0] = y.astype(o_ref.dtype)
+def _wrap(t, n):
+    """t in (-n, 2n) -> t mod n, without a vector remainder."""
+    t = jnp.where(t < 0, t + n, t)
+    return jnp.where(t >= n, t - n, t)
+
+
+def _train_kernel(params_ref, mean_ref, inv_ref, lane_w_ref, lane_c_ref,
+                  x_ref, o_ref, *, w, c):
+    i = pl.program_id(0)
+    flip = params_ref[i, 0]
+    x = x_ref[0].astype(jnp.float32)  # (H, W*C)
+    h, wc = x.shape
+    # out row r reads input row (r - dy) mod H
+    dy = params_ref[i, 1] % h
+    r = jax.lax.broadcasted_iota(jnp.int32, (h, h), 0)
+    k = jax.lax.broadcasted_iota(jnp.int32, (h, h), 1)
+    ph = (k == _wrap(r - dy, h)).astype(jnp.float32)
+    x = jnp.dot(ph, x, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+    # out lane (wo, ch) reads input lane (f((wo - dx) mod W), ch), with
+    # f the horizontal flip when the sample's flip bit is set
+    dx = params_ref[i, 2] % w
+    u = _wrap(lane_w_ref[...] - dx, w)  # (1, W*C)
+    src = jnp.where(flip > 0, w - 1 - u, u) * c + lane_c_ref[...]
+    kk = jax.lax.broadcasted_iota(jnp.int32, (wc, wc), 0)
+    pw = (kk == src).astype(jnp.float32)
+    x = jnp.dot(x, pw, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+    o_ref[0] = ((x - mean_ref[...]) * inv_ref[...]).astype(o_ref.dtype)
 
 
 def _eval_kernel(mean_ref, inv_ref, x_ref, o_ref):
     x = x_ref[0].astype(jnp.float32)
-    y = (x - mean_ref[0]) * inv_ref[0]
-    o_ref[0] = y.astype(o_ref.dtype)
+    o_ref[0] = ((x - mean_ref[...]) * inv_ref[...]).astype(o_ref.dtype)
+
+
+def _lane_pattern(v, w):
+    """(C,) per-channel values -> (1, W*C) lane pattern of the
+    lane-dense (H, W*C) image view."""
+    return jnp.tile(v.astype(jnp.float32), w).reshape(1, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
-def fused_input_train(x, params, mean, inv_std, *, out_dtype,
-                      interpret=False):
+def fused_input_train(x, params, mean, inv_std, *, out_dtype, interpret):
     """(B, H, W, C) raw pixels -> augmented/normalized ``out_dtype``.
 
     ``params`` is (B, 4) int32 from ops.input_augment_params; ``mean``
     and ``inv_std`` are (C,) f32 (inv_std precomputed so the kernel is
     multiply-only on the hot path)."""
     b, h, w, c = x.shape
-    mean = jnp.broadcast_to(mean.astype(jnp.float32), (1, c))
-    inv_std = jnp.broadcast_to(inv_std.astype(jnp.float32), (1, c))
-    return pl.pallas_call(
-        _train_kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda i: (i, 0)),
-            pl.BlockSpec((1, c), lambda i: (0, 0)),
-            pl.BlockSpec((1, c), lambda i: (0, 0)),
-            pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, c), out_dtype),
+    lane = jnp.arange(w * c, dtype=jnp.int32).reshape(1, -1)
+    row = pl.BlockSpec((1, w * c), lambda i, p: (0, 0))
+    out = pl.pallas_call(
+        functools.partial(_train_kernel, w=w, c=c),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b,),
+            in_specs=[row, row, row, row,
+                      pl.BlockSpec((1, h, w * c), lambda i, p: (i, 0, 0))],
+            out_specs=pl.BlockSpec((1, h, w * c), lambda i, p: (i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, w * c), out_dtype),
         interpret=interpret,
-    )(params.astype(jnp.int32), mean, inv_std, x)
+    )(params.astype(jnp.int32), _lane_pattern(mean, w),
+      _lane_pattern(inv_std, w), lane // c, lane % c,
+      x.reshape(b, h, w * c))
+    return out.reshape(b, h, w, c)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
-def fused_input_eval(x, mean, inv_std, *, out_dtype, interpret=False):
+def fused_input_eval(x, mean, inv_std, *, out_dtype, interpret):
     """Eval variant: per-channel normalize + cast, no augmentation."""
     b, h, w, c = x.shape
-    mean = jnp.broadcast_to(mean.astype(jnp.float32), (1, c))
-    inv_std = jnp.broadcast_to(inv_std.astype(jnp.float32), (1, c))
-    return pl.pallas_call(
+    row = pl.BlockSpec((1, w * c), lambda i: (0, 0))
+    out = pl.pallas_call(
         _eval_kernel,
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, c), lambda i: (0, 0)),
-            pl.BlockSpec((1, c), lambda i: (0, 0)),
-            pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, c), out_dtype),
+        in_specs=[row, row,
+                  pl.BlockSpec((1, h, w * c), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((1, h, w * c), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, w * c), out_dtype),
         interpret=interpret,
-    )(mean, inv_std, x)
+    )(_lane_pattern(mean, w), _lane_pattern(inv_std, w),
+      x.reshape(b, h, w * c))
+    return out.reshape(b, h, w, c)

@@ -63,7 +63,7 @@ def _kernel_wd(scalars_ref, g_ref, p_ref, d_ref, m_ref, wd_ref,
 
 
 def fused_update_2d(g, p, d, m, scalars, *, mu1, mu2, eps, eta_rmsprop,
-                    weight_decay, interpret=True, block_rows=BLOCK_ROWS):
+                    weight_decay, interpret, block_rows=BLOCK_ROWS):
     """g/p/d/m: (rows, 128) fp32; scalars: (1, 2) [eta, alpha_sgd].
 
     ``weight_decay`` is either a python float (baked into the kernel, the
@@ -124,121 +124,124 @@ def fused_update_2d(g, p, d, m, scalars, *, mu1, mu2, eps, eta_rmsprop,
 # packed stream, and the trust-scaled momentum update.
 # ---------------------------------------------------------------------------
 
-SEG_BLOCK_ROWS = 8  # one-hot tile (8*128 elems x padded segment count)
+SEG_BLOCK_ROWS = 256  # rows of 128 lanes per grid step
 
 
-def _seg_sq_kernel(g_ref, p_ref, wd_ref, seg_ref, out_ref):
-    """Accumulate per-segment sums of p^2 and (g + wd*p)^2 into rows 0/1
-    of an (8, n_seg_padded) f32 output block revisited by every grid
-    step (rows 2..7 are min-tile padding and stay zero). The per-segment
-    scatter is a one-hot matmul: (1, bm*128) @ (bm*128, n_seg)."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+def _seg_sq_kernel(g_ref, p_ref, wd_ref, seg_ref, pp_ref, gg_ref):
+    """Accumulate per-segment sums of p^2 and (g + wd*p)^2 into two
+    (n_seg_padded, 128) per-lane accumulators revisited by every grid
+    step; the wrapper sums their lanes. Row by row: a (n_seg, 128)
+    segment mask selects each lane's value into its segment's row —
+    exact selects, f32 adds, no lane-changing reshape (which the TPU
+    compiler refuses)."""
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        pp_ref[...] = jnp.zeros_like(pp_ref)
+        gg_ref[...] = jnp.zeros_like(gg_ref)
 
-    g = g_ref[...]
-    p = p_ref[...]
-    ge = g + wd_ref[...] * p
-    seg = seg_ref[...]
-    bm, lanes = seg.shape
-    n_seg = out_ref.shape[1]
-    onehot = (seg.reshape(bm * lanes, 1) ==
-              jax.lax.broadcasted_iota(jnp.int32, (1, n_seg), 1)
-              ).astype(jnp.float32)
-    p_row = jnp.dot((p * p).reshape(1, bm * lanes), onehot,
-                    preferred_element_type=jnp.float32)
-    g_row = jnp.dot((ge * ge).reshape(1, bm * lanes), onehot,
-                    preferred_element_type=jnp.float32)
-    zeros = jnp.zeros((out_ref.shape[0] - 2, n_seg), jnp.float32)
-    out_ref[...] = out_ref[...] + jnp.concatenate([p_row, g_row, zeros], 0)
+    sid = jax.lax.broadcasted_iota(jnp.int32, pp_ref.shape, 0)
+
+    def row(r, acc):
+        acc_p, acc_g = acc
+        p = p_ref[pl.ds(r, 1), :]
+        ge = g_ref[pl.ds(r, 1), :] + wd_ref[pl.ds(r, 1), :] * p
+        hit = sid == seg_ref[pl.ds(r, 1), :]
+        return (acc_p + jnp.where(hit, p * p, 0.0),
+                acc_g + jnp.where(hit, ge * ge, 0.0))
+
+    acc_p, acc_g = jax.lax.fori_loop(
+        0, g_ref.shape[0], row,
+        (jnp.zeros(pp_ref.shape, jnp.float32),
+         jnp.zeros(gg_ref.shape, jnp.float32)))
+    pp_ref[...] += acc_p
+    gg_ref[...] += acc_g
 
 
-def seg_sq_partials_2d(g, p, wd, seg, n_seg_padded, *, interpret=True,
+def _pad_rows(block_rows, rows, streams, seg, seg_fill):
+    """Zero-pad (rows, 128) streams and the segment ids (with
+    ``seg_fill``) up to a ``block_rows`` multiple."""
+    pad = (-rows) % block_rows
+    if not pad:
+        return streams, seg
+    zrow = ((0, pad), (0, 0))
+    return ([jnp.pad(x, zrow) for x in streams],
+            jnp.pad(seg, zrow, constant_values=seg_fill))
+
+
+def seg_sq_partials_2d(g, p, wd, seg, n_seg_padded, *, interpret,
                        block_rows=SEG_BLOCK_ROWS):
     """g/p/wd: (rows, 128) fp32; seg: (rows, 128) int32 segment ids.
     Returns (2, n_seg_padded) f32: per-segment sums of [p^2, (g+wd*p)^2].
 
-    ``n_seg_padded`` must be a lane multiple (the wrapper in
+    ``n_seg_padded`` must be a multiple of 8 (the wrapper in
     kernels/ops.py pads and slices). Row padding points the pad elements
     at segment ``n_seg_padded - 1`` with zero values — an exact +0.0."""
     rows = g.shape[0]
     block_rows = min(block_rows, rows)
-    pad = (-rows) % block_rows
-    if pad:
-        zrow = ((0, pad), (0, 0))
-        g = jnp.pad(g, zrow)
-        p = jnp.pad(p, zrow)
-        wd = jnp.pad(wd, zrow)
-        seg = jnp.pad(seg, zrow, constant_values=n_seg_padded - 1)
-    padded_rows = rows + pad
-    grid = (padded_rows // block_rows,)
+    (g, p, wd), seg = _pad_rows(block_rows, rows, [g, p, wd], seg,
+                                n_seg_padded - 1)
+    grid = (g.shape[0] // block_rows,)
     tile = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    out = pl.pallas_call(
+    acc = pl.BlockSpec((n_seg_padded, LANES), lambda i: (0, 0))
+    pp, gg = pl.pallas_call(
         _seg_sq_kernel,
         grid=grid,
         in_specs=[tile, tile, tile, tile],
-        out_specs=pl.BlockSpec((8, n_seg_padded), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((8, n_seg_padded), jnp.float32),
+        out_specs=[acc, acc],
+        out_shape=[jax.ShapeDtypeStruct((n_seg_padded, LANES),
+                                        jnp.float32)] * 2,
         interpret=interpret,
     )(g, p, wd, seg)
-    return out[:2]
+    return jnp.stack([pp.sum(axis=1), gg.sum(axis=1)])
 
 
 def _lars_update_kernel(scalars_ref, trust_ref, g_ref, p_ref, d_ref,
                         wd_ref, seg_ref, p_out, d_out, *, mu1):
-    """Trust-scaled momentum step. Per-element trust is looked up from
-    the (1, n_seg) trust row by an exact one-hot dot — a single 1.0
-    coefficient plus zeros, so the gather adds no rounding."""
+    """Trust-scaled momentum step. Per-element trust is looked up row by
+    row: a (n_seg, 128) segment mask selects the trust column
+    (``trust_ref`` holds trust[s] in every lane of row s) and a sublane
+    sum keeps the single hit — one trust value plus zeros, so the
+    lookup adds no rounding."""
     eta = scalars_ref[0, 0]
-    g = g_ref[...]
-    p = p_ref[...]
-    d = d_ref[...]
-    ge = g + wd_ref[...] * p
-    seg = seg_ref[...]
-    bm, lanes = seg.shape
-    n_seg = trust_ref.shape[1]
-    onehot = (seg.reshape(bm * lanes, 1) ==
-              jax.lax.broadcasted_iota(jnp.int32, (1, n_seg), 1)
-              ).astype(jnp.float32)
-    t = jnp.dot(onehot, trust_ref[...].reshape(n_seg, 1),
-                preferred_element_type=jnp.float32).reshape(bm, lanes)
-    d_new = mu1 * d - t * ge
-    p_out[...] = p + eta * d_new
-    d_out[...] = d_new
+    sid = jax.lax.broadcasted_iota(jnp.int32, trust_ref.shape, 0)
+    trust = trust_ref[...]
+
+    def row(r, carry):
+        sl = pl.ds(r, 1)
+        p = p_ref[sl, :]
+        ge = g_ref[sl, :] + wd_ref[sl, :] * p
+        t = jnp.sum(jnp.where(sid == seg_ref[sl, :], trust, 0.0), axis=0,
+                    keepdims=True)
+        d_new = mu1 * d_ref[sl, :] - t * ge
+        p_out[sl, :] = p + eta * d_new
+        d_out[sl, :] = d_new
+        return carry
+
+    jax.lax.fori_loop(0, g_ref.shape[0], row, 0)
 
 
-def lars_update_2d(g, p, d, wd, seg, trust_row, scalars, *, mu1,
-                   interpret=True, block_rows=SEG_BLOCK_ROWS):
-    """g/p/d/wd: (rows, 128) fp32; seg: (rows, 128) int32; trust_row:
-    (1, n_seg_padded) fp32 (1.0 in the padding columns); scalars: (1, 2)
-    [eta, unused]. Returns (p', d')."""
+def lars_update_2d(g, p, d, wd, seg, trust_col, scalars, *, mu1,
+                   interpret, block_rows=SEG_BLOCK_ROWS):
+    """g/p/d/wd: (rows, 128) fp32; seg: (rows, 128) int32; trust_col:
+    (n_seg_padded, 128) fp32 with trust[s] across row s (1.0 in the
+    padding rows); scalars: (1, 2) [eta, unused]. Returns (p', d')."""
     rows = g.shape[0]
-    n_seg = trust_row.shape[1]
+    n_seg = trust_col.shape[0]
     block_rows = min(block_rows, rows)
-    pad = (-rows) % block_rows
-    if pad:
-        zrow = ((0, pad), (0, 0))
-        g = jnp.pad(g, zrow)
-        p = jnp.pad(p, zrow)
-        d = jnp.pad(d, zrow)
-        wd = jnp.pad(wd, zrow)
-        seg = jnp.pad(seg, zrow, constant_values=n_seg - 1)
-    padded_rows = rows + pad
+    (g, p, d, wd), seg = _pad_rows(block_rows, rows, [g, p, d, wd], seg,
+                                   n_seg - 1)
+    padded_rows = g.shape[0]
     grid = (padded_rows // block_rows,)
     tile = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     outs = pl.pallas_call(
         functools.partial(_lars_update_kernel, mu1=mu1),
         grid=grid,
         in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0)),
-                  pl.BlockSpec((1, n_seg), lambda i: (0, 0)),
+                  pl.BlockSpec((n_seg, LANES), lambda i: (0, 0)),
                   tile, tile, tile, tile, tile],
         out_specs=[tile, tile],
         out_shape=[jax.ShapeDtypeStruct((padded_rows, LANES),
                                         jnp.float32)] * 2,
         interpret=interpret,
-    )(scalars, trust_row, g, p, d, wd, seg)
-    if pad:
-        outs = [o[:rows] for o in outs]
-    return tuple(outs)
+    )(scalars, trust_col, g, p, d, wd, seg)
+    return tuple(o[:rows] for o in outs)

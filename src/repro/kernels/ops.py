@@ -81,14 +81,14 @@ def fused_segment_sq_partials(p, g, wd, seg, num_segments):
     """(2, num_segments) f32 per-segment sums of [p^2, (g+wd*p)^2] over a
     flat stream — the Pallas twin of stacking two
     ``bucketing.segment_sq_partials`` calls (stream-LARS trust norms,
-    DESIGN.md §11). The one-hot-matmul fold order differs from
-    segment_sum's, so this path is allclose- (not bitwise-) parity
+    DESIGN.md §11). The per-lane accumulation's fold order differs
+    from segment_sum's, so this path is allclose- (not bitwise-) parity
     tested and excluded from the bitwise parity matrix."""
     n = p.size
     rows = max(1, -(-n // LANES))
     pad = rows * LANES - n
     flat = _lars_flat(n, rows, pad)
-    n_seg_padded = -(-num_segments // LANES) * LANES
+    n_seg_padded = -(-num_segments // 8) * 8
     out = _fu.seg_sq_partials_2d(
         flat(g), flat(p), flat(wd),
         flat(seg, fill=num_segments - 1, dtype=jnp.int32),
@@ -98,25 +98,25 @@ def fused_segment_sq_partials(p, g, wd, seg, num_segments):
 
 def fused_lars_update(g, p, d, wd, seg, trust, eta, mu1):
     """(p', d') trust-scaled momentum update on a flat stream: one fused
-    pass over 5 streams with the per-segment trust row resident in VMEM
-    (stream-LARS fused path, DESIGN.md §11)."""
+    pass over 5 streams with the per-segment trust column resident in
+    VMEM (stream-LARS fused path, DESIGN.md §11)."""
     orig_dtype = p.dtype
     n = p.size
     rows = max(1, -(-n // LANES))
     pad = rows * LANES - n
     flat = _lars_flat(n, rows, pad)
     num_segments = trust.shape[0]
-    n_seg_padded = -(-num_segments // LANES) * LANES
-    trust_row = jnp.concatenate(
+    n_seg_padded = -(-num_segments // 8) * 8
+    trust_col = jnp.broadcast_to(jnp.concatenate(
         [trust.astype(jnp.float32),
          jnp.ones((n_seg_padded - num_segments,), jnp.float32)]
-    ).reshape(1, n_seg_padded)
+    )[:, None], (n_seg_padded, LANES))
     scalars = jnp.stack([jnp.asarray(eta, jnp.float32),
                          jnp.zeros((), jnp.float32)]).reshape(1, 2)
     p_new, d_new = _fu.lars_update_2d(
         flat(g), flat(p), flat(d), flat(wd),
         flat(seg, fill=n_seg_padded - 1, dtype=jnp.int32),
-        trust_row, scalars, mu1=mu1, interpret=_interpret())
+        trust_col, scalars, mu1=mu1, interpret=_interpret())
 
     def unflat(x, dtype):
         return x.reshape(-1)[:n].astype(dtype)

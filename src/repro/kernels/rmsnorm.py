@@ -25,7 +25,7 @@ def _kernel(x_ref, scale_ref, o_ref, *, eps):
     o_ref[...] = (x32 * inv).astype(x.dtype) * scale_ref[...]
 
 
-def rmsnorm(x, scale, *, eps: float = 1e-5, interpret: bool = True,
+def rmsnorm(x, scale, *, eps: float = 1e-5, interpret: bool,
             row_block: int = ROW_BLOCK):
     """x: (..., d); scale: (d,). Returns RMS-normalized x * scale."""
     orig_shape = x.shape

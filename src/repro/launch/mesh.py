@@ -2,27 +2,43 @@
 importing this module never touches jax device state."""
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import ModelConfig, ParallelConfig, ShapeConfig
 
-# v5e hardware constants for the roofline (per chip)
+# v5e hardware constants for the dry run's roofline model (per chip);
+# nothing on the training path reads them
 PEAK_FLOPS_BF16 = 197e12  # FLOP/s
 HBM_BW = 819e9  # B/s
 ICI_BW = 50e9  # B/s per link
 
 
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices=None):
+    """The one mesh constructor: ``jax.make_mesh`` with every axis
+    ``AxisType.Auto``. ``jax.make_mesh`` defaults to Explicit axes,
+    under which ``with_sharding_constraint`` refuses the mesh and the
+    GSPMD partitioner raises ``ShardingTypeError`` on gathers and
+    dynamic slices; every mesh in this repo leaves placement to the
+    partitioner (GSPMD) or to shard_map, so all axes are Auto."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_small_mesh(data: int = 4, model: int = 2):
     """Virtual-device mesh for tests (XLA_FLAGS host device count)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def preferred_mesh(cfg: ModelConfig, *, multi_pod: bool = False):
@@ -37,7 +53,7 @@ def preferred_mesh(cfg: ModelConfig, *, multi_pod: bool = False):
             and cfg.param_count() > 3e9:
         shape = (2, 32, 8) if multi_pod else (32, 8)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     return make_production_mesh(multi_pod=multi_pod)
 
 

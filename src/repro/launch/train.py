@@ -14,6 +14,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
 import time
 from typing import Any, Dict, Optional
 
@@ -33,6 +34,7 @@ from repro.configs import (
 )
 from repro.data import AugmentedSource, StepStampSource, make_data
 from repro.distributed.sharding import make_rules, tree_shardings
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, init_model_state
 from repro.models.common import unbox
 from repro.optim import make_optimizer
@@ -49,6 +51,27 @@ from repro.training.step import (
     make_eval_step,
     make_train_step,
 )
+
+
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+# unset: a fixed directory inside the checkout (listed in .gitignore).
+# The path is part of what a later run must match to hit the cache, so it
+# never depends on a temporary name, a process id or the time.
+COMPILE_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here; otherwise the cache goes to
+    ``COMPILE_CACHE_DIR``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def build_train_setup(cfg, *, global_batch: int, seq_len: int,
@@ -205,14 +228,14 @@ def build_train_setup(cfg, *, global_batch: int, seq_len: int,
     if ef_residual is not None:
         state["ef_residual"] = ef_residual
 
-    def _finalize_step(step):
+    def _finalize_step(step, **jit_kwargs):
         # sentinel wraps OUTSIDE the sync-mode builder and INSIDE jit:
         # the skip gate must live in the compiled program because the
         # jitted step donates its input state (DESIGN.md §13)
         if sentinel:
             from repro.resilience.sentinel import wrap_step_with_sentinel
             step = wrap_step_with_sentinel(step)
-        return jit_train_step(step)
+        return jit_train_step(step, **jit_kwargs)
 
     rules = None
     state_shardings = None
@@ -239,7 +262,26 @@ def build_train_setup(cfg, *, global_batch: int, seq_len: int,
                 step = make_dp_shardmap_train_step(
                     model, optimizer, train_cfg, mesh, parallel.dp_axes,
                     input_transform=input_transform)
-            train_step = _finalize_step(step)
+            # the state goes in and comes out with one placement
+            # (replicated params/opt, per-worker BN/EF rows, ZeRO-sharded
+            # stream state), so every step reuses the first step's
+            # executable instead of compiling again for new shardings
+            rep = NamedSharding(mesh, P())
+            per_worker = NamedSharding(mesh, P(parallel.dp_axes))
+            placed = {
+                "params": jax.tree.map(lambda _: rep, state["params"]),
+                "opt": {k: jax.tree.map(
+                    lambda _, sh=(per_worker if zero_dp and k != "step"
+                                  else rep): sh, v)
+                    for k, v in state["opt"].items()},
+                "model_state": jax.tree.map(lambda _: per_worker,
+                                            state["model_state"]),
+            }
+            if "ef_residual" in state:
+                placed["ef_residual"] = jax.tree.map(
+                    lambda _: per_worker, state["ef_residual"])
+            state = jax.device_put(state, placed)
+            train_step = _finalize_step(step, out_shardings=(placed, rep))
         else:
             p_shard = tree_shardings(axes, mesh, rules)
             state_shardings = {
@@ -432,6 +474,7 @@ def main():
     ap.add_argument("--log-json", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.chaos:
         args.sentinel = True
     if args.sentinel and args.epochs is None:
@@ -444,10 +487,10 @@ def main():
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     elif args.dp_mode == "shardmap":
         # explicit DP needs a mesh; default to pure-DP over all devices
-        mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+        mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
 
     opt_cfg = OptimizerConfig(kind=args.optimizer, schedule=args.schedule)
     input_cfg = None

@@ -29,7 +29,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import OptimizerConfig
-from repro.core.optimizer import HybridHyper, alpha_rmsprop
+from repro.core.optimizer import (
+    HybridHyper,
+    hybrid_update,
+    momentum_sgd_update,
+)
 from repro.core.schedules import alpha_sgd_schedule, make_lr_schedule
 from repro.distributed.bucketing import (
     BucketPlan,
@@ -120,13 +124,8 @@ def make_stream_optimizer(cfg: OptimizerConfig, steps_per_epoch: int,
             p_new, d_new, m_new = kops.fused_hybrid_update(
                 g_shard, p_shard, d32, m32, h, wd_shard)
         else:
-            g = g_shard.astype(jnp.float32) + wd_shard * \
-                p_shard.astype(jnp.float32)
-            m_new = h.mu2 * m32 + (1.0 - h.mu2) * jnp.square(g)
-            coef = h.alpha_sgd + alpha_rmsprop(h) / (jnp.sqrt(m_new) + h.eps)
-            d_new = h.mu1 * d32 - coef * g
-            p_new = (p_shard.astype(jnp.float32) + h.eta * d_new
-                     ).astype(p_shard.dtype)
+            p_new, d_new, m_new = hybrid_update(g_shard, p_shard, d32, m32,
+                                                h, wd_shard)
         metrics = {"lr": eta, "alpha_sgd": a_sgd, "epoch": epoch}
         return (p_new, d_new.astype(state_dtype), m_new.astype(state_dtype),
                 metrics)
@@ -144,8 +143,8 @@ def _make_stream_momentum_sgd(cfg: OptimizerConfig, steps_per_epoch: int,
     packed stream so ``--zero`` runs it too (the audit matrix lowers
     every mode x optimizer cell). Same ``update_shard`` signature as the
     rmsprop_warmup stream — ``m`` rides along untouched (zeros) so the
-    ZeRO caller's state plumbing is identical — and the math inlines
-    ``core.optimizer.momentum_sgd_update`` with the decay folded in
+    ZeRO caller's state plumbing is identical — and the math is
+    ``core.optimizer.momentum_sgd_update`` with the decay applied
     elementwise: ``wd_shard`` is 0.0 off the decay set, and adding
     ``0.0 * p`` is value-neutral, so the parameters match the
     replicated tree update exactly (tests/test_audit.py)."""
@@ -168,12 +167,9 @@ def _make_stream_momentum_sgd(cfg: OptimizerConfig, steps_per_epoch: int,
                      wd_shard):
         epoch = step.astype(jnp.float32) / steps_per_epoch
         eta = lr_fn(epoch)
-        d32 = delta_shard.astype(jnp.float32)
-        g = g_shard.astype(jnp.float32) + wd_shard * \
-            p_shard.astype(jnp.float32)
-        d_new = cfg.mu1 * d32 - g
-        p_new = (p_shard.astype(jnp.float32) + eta * d_new
-                 ).astype(p_shard.dtype)
+        h = HybridHyper(eta=eta, alpha_sgd=jnp.float32(1.0), mu1=cfg.mu1)
+        p_new, d_new = momentum_sgd_update(
+            g_shard, p_shard, delta_shard.astype(jnp.float32), h, wd_shard)
         metrics = {"lr": eta, "epoch": epoch}
         return (p_new, d_new.astype(state_dtype),
                 m_shard.astype(state_dtype), metrics)

@@ -33,7 +33,7 @@ format; ``dp_mode`` selects the mechanism):
     back — roughly half the wire volume and 1/N the update FLOPs/state
     memory, bitwise-identical end state (DESIGN.md §9). Composes with
     both the plain bucketed path and the overlapped path (the scatter
-    launches between segment VJPs behind the same barrier pipeline).
+    launches between segment VJPs in the same pipeline).
 """
 from __future__ import annotations
 
@@ -277,8 +277,6 @@ def _wrap_dp_step(local_step, mesh: Mesh, dp_axes: Sequence[str],
     packed-stream side inputs (wd/segment streams) ride in as sharded
     shard_map *inputs* instead of being baked into every rank's program
     as full-stream trace constants (DESIGN.md §11)."""
-    from jax.experimental.shard_map import shard_map
-
     batch_spec = P(tuple(dp_axes))
     state_spec = P(tuple(dp_axes))  # per-worker last-minibatch BN / EF
 
@@ -311,8 +309,8 @@ def _wrap_dp_step(local_step, mesh: Mesh, dp_axes: Sequence[str],
             aux, aux_specs = aux_builder(state, batch)
             in_specs += (aux_specs,)
             args += (aux,)
-        fn = shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         outs = fn(*args)
         new_params, new_mstate, new_opt, metrics = outs[:4]
         new_state = {"params": new_params, "opt": new_opt,
@@ -483,6 +481,7 @@ def _zero_sharded_update(optimizer, plan, param_tree, g_shard, opt,
     folds it into the stacked metrics pmean, DESIGN.md §8)."""
     import dataclasses as _dc
 
+    from repro.core.compression import chained
     from repro.distributed.bucketing import (
         hierarchical_all_gather,
         pack,
@@ -519,15 +518,15 @@ def _zero_sharded_update(optimizer, plan, param_tree, g_shard, opt,
             wd_shard)
         new_opt = {"step": opt["step"] + 1, "delta": d_new, "m": m_new}
 
-    off, gathered = 0, []
-    for c in chunks:
-        piece = jax.lax.slice(p_new, (off,), (off + c,))
-        if hier is not None:
-            gathered.append(hierarchical_all_gather(piece, hier))
-        else:
-            gathered.append(jax.lax.all_gather(piece, tuple(dp_axes),
-                                               tiled=True))
-        off += c
+    offs = [sum(chunks[:i]) for i in range(len(chunks))]
+    pieces = [jax.lax.slice(p_new, (o,), (o + c,))
+              for o, c in zip(offs, chunks)]
+    if hier is not None:
+        gathered = chained(pieces,
+                           lambda x: hierarchical_all_gather(x, hier))
+    else:
+        gathered = chained(pieces, lambda x: jax.lax.all_gather(
+            x, tuple(dp_axes), tiled=True))
     new_param_tree = unpack(gathered, p_plan)
     return new_param_tree, new_opt, opt_metrics, local_sq
 
@@ -732,7 +731,7 @@ def _make_dp_zero_train_step(model, optimizer, train_cfg: TrainConfig,
     packing exactly as in ``bucketed_psum_ef`` — which is what keeps the
     residuals (and everything downstream) bitwise-equal to the
     all-reduce path."""
-    from repro.core.compression import apply_error_feedback
+    from repro.core.compression import apply_error_feedback, chained
     from repro.distributed.bucketing import (
         hierarchical_psum_scatter,
         pack,
@@ -762,14 +761,14 @@ def _make_dp_zero_train_step(model, optimizer, train_cfg: TrainConfig,
         # shard-aligned plan: every bucket splits evenly across the ranks
         plan = plan_buckets(quant, parallel.bucket_bytes, wire, align=n)
         if hier is not None:
-            g_shard = jnp.concatenate(
-                [hierarchical_psum_scatter(b, hier)
-                 for b in pack(quant, plan)])
+            g_shard = jnp.concatenate(chained(
+                pack(quant, plan),
+                lambda b: hierarchical_psum_scatter(b, hier)))
         else:
-            g_shard = jnp.concatenate(
-                [jax.lax.psum_scatter(b, tuple(dp_axes),
-                                      scatter_dimension=0, tiled=True)
-                 for b in pack(quant, plan)])
+            g_shard = jnp.concatenate(chained(
+                pack(quant, plan),
+                lambda b: jax.lax.psum_scatter(
+                    b, tuple(dp_axes), scatter_dimension=0, tiled=True)))
         new_params, new_opt, opt_metrics, local_sq = _zero_sharded_update(
             optimizer, plan, params, g_shard, opt, n, dp_axes, mesh, aux,
             hier=hier)
@@ -804,7 +803,7 @@ def _make_dp_stream_train_step(model, optimizer, train_cfg: TrainConfig,
     makes this path's parameters bitwise-equal to ``--zero``'s
     (tests/test_lars_stream.py). Error feedback stays rank-local and
     full-tree, applied before packing, as in ``bucketed_psum_ef``."""
-    from repro.core.compression import apply_error_feedback
+    from repro.core.compression import apply_error_feedback, chained
     from repro.distributed.bucketing import (
         hierarchical_psum,
         pack,
@@ -837,11 +836,11 @@ def _make_dp_stream_train_step(model, optimizer, train_cfg: TrainConfig,
         # reduce-scatter would — the bitwise-parity contract above
         plan = plan_buckets(quant, parallel.bucket_bytes, wire, align=n)
         if hier is not None:
-            synced = [hierarchical_psum(b, hier)
-                      for b in pack(quant, plan)]
+            synced = chained(pack(quant, plan),
+                             lambda b: hierarchical_psum(b, hier))
         else:
-            synced = [jax.lax.psum(b, tuple(dp_axes))
-                      for b in pack(quant, plan)]
+            synced = chained(pack(quant, plan),
+                             lambda b: jax.lax.psum(b, tuple(dp_axes)))
         g_stream = _cast_divide_stream(jnp.concatenate(synced), plan, n)
         new_params, new_opt, opt_metrics, local_sq = _stream_full_update(
             optimizer, plan, params, g_stream, opt, n, dp_axes, mesh, aux)
@@ -876,14 +875,15 @@ def make_dp_overlap_train_step(model, optimizer: Optimizer,
     backward pass: the model's loss is split into K segments
     (``model.loss_segments``), each segment's VJP is taken independently,
     and every ready-order bucket's psum is issued the moment the
-    bucket's last leaf exists. ``optimization_barrier`` pins each
-    collective's completion one segment downstream of its launch, so the
-    interconnect works on bucket i while the VJP of segment i-1 computes
-    — the paper's "aggregate finished layers in parallel with backprop"
-    (Goyal et al. §Gradient aggregation; verified from the compiled HLO
-    by ``launch/hlo_analysis.py:interleave_report``).
+    bucket's last leaf exists. A data edge (``compression.after``) from
+    each collective's result into the cotangent of the segment two
+    downstream pins its completion there, so the interconnect works on
+    bucket i while the VJP of segment i-1 computes — the paper's
+    "aggregate finished layers in parallel with backprop" (Goyal et al.
+    §Gradient aggregation; verified from the compiled HLO by
+    ``launch/hlo_analysis.py:interleave_report``).
     """
-    from repro.core.compression import apply_error_feedback
+    from repro.core.compression import after, apply_error_feedback
     from repro.distributed.bucketing import (
         hierarchical_psum,
         hierarchical_psum_scatter,
@@ -945,26 +945,25 @@ def make_dp_overlap_train_step(model, optimizer: Optimizer,
         if use_ef:
             local_residual = jax.tree.map(lambda x: x[0], residual)
             res_rev = list(reversed(staged.split_tree(local_residual)))
-        n = jax.lax.psum(1, dp_axes)
+        n = jax.lax.axis_size(dp_axes)
         # ---- backward: VJP segment i, launch ready buckets, require
         # completion only before segment i-2 (one-segment-deep pipeline:
         # bucket i's wire time hides behind segment i-1's compute). With
         # zero_dp the launched collective is the bucket's reduce-scatter
-        # — same launch points, same barrier pipeline. ----
+        # — same launch points, same pipeline. Completion is pinned by a
+        # data edge into segment i-2's cotangent (``after``). ----
         ct: Any = jnp.ones_like(loss)
         synced: Dict[int, jax.Array] = {}
         pending: List[List[int]] = []  # launched ids, newest last
+        last = None  # newest collective result: the launch chain
         pack_carry = None
         new_res_rev: List[PyTree] = []
         for ridx, i in enumerate(reversed(range(n_seg))):
             if len(pending) >= 2:
-                ids = pending.pop(0)
-                if ids:
-                    barred = jax.lax.optimization_barrier(
-                        (ct, tuple(synced[b] for b in ids)))
-                    ct = barred[0]
-                    for b, v in zip(ids, barred[1]):
-                        synced[b] = v
+                # segment i's VJP input waits on these collectives
+                for b in pending.pop(0):
+                    ct = jax.tree.map(lambda x, d=synced[b]: after(x, d),
+                                      ct)
             g_seg, ct = vjps[i](ct)
             if use_ef:
                 g_seg, r_new = apply_error_feedback(g_seg, res_rev[ridx],
@@ -974,8 +973,12 @@ def make_dp_overlap_train_step(model, optimizer: Optimizer,
             launched = []
             for b, arr in ready:
                 # with a hierarchy the whole two-level schedule launches
-                # here; the barrier pipeline pins only its completion,
-                # exactly as for the flat collective (DESIGN.md §14)
+                # here; the pipeline pins only its completion, exactly
+                # as for the flat collective (DESIGN.md §14). Each
+                # launch is chained after the previous one, so no
+                # combiner merges two buckets into one collective.
+                if last is not None:
+                    arr = after(arr, last)
                 if use_zero:
                     synced[b] = (
                         hierarchical_psum_scatter(arr, hier)
@@ -987,6 +990,7 @@ def make_dp_overlap_train_step(model, optimizer: Optimizer,
                     synced[b] = (hierarchical_psum(arr, hier)
                                  if hier is not None else
                                  jax.lax.psum(arr, dp_axes))
+                last = synced[b]
                 launched.append(b)
             pending.append(launched)
         assert len(synced) == plan.n_buckets, (len(synced), plan.n_buckets)

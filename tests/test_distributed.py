@@ -25,10 +25,11 @@ def run_py(body: str, timeout=420) -> str:
 def test_gspmd_train_step_sharded():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import OptimizerConfig, get_config, reduced_config
         from repro.launch.train import build_train_setup
         cfg = reduced_config(get_config('llama3.2-1b'))
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         model, state, step, data, put, _ = build_train_setup(
             cfg, global_batch=8, seq_len=32,
             opt_cfg=OptimizerConfig(), steps_per_epoch=5, mesh=mesh)
@@ -45,10 +46,11 @@ def test_paper_faithful_shardmap_dp_matches_gspmd():
     same training trajectory as the GSPMD step (up to wire rounding)."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import OptimizerConfig, get_config, reduced_config
         from repro.launch.train import build_train_setup
         cfg = reduced_config(get_config('resnet50'))
-        mesh = jax.make_mesh((8, 1), ('data', 'model'))
+        mesh = make_mesh((8, 1), ('data', 'model'))
         losses = {}
         for mode in ('gspmd', 'shardmap'):
             # sync_bn isolates the gradient-sync comparison: without it
@@ -78,11 +80,12 @@ def test_bn_stats_per_worker_and_finalize():
     pre-validation all-reduce (mean over workers) equals global stats."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import OptimizerConfig, get_config, reduced_config
         from repro.launch.train import build_train_setup
         from repro.training.step import finalize_worker_bn_stats
         cfg = reduced_config(get_config('resnet50'))
-        mesh = jax.make_mesh((8, 1), ('data', 'model'))
+        mesh = make_mesh((8, 1), ('data', 'model'))
         model, state, step, data, put, _ = build_train_setup(
             cfg, global_batch=16, seq_len=16, opt_cfg=OptimizerConfig(),
             steps_per_epoch=5, mesh=mesh, dp_mode='shardmap')
@@ -106,10 +109,11 @@ def test_bn_stats_per_worker_and_finalize():
 def test_compressed_psum_wire_dtype_and_value():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from repro.launch.mesh import make_mesh
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.compression import compressed_psum
-        mesh = jax.make_mesh((8,), ('data',))
+        mesh = make_mesh((8,), ('data',))
         x = jnp.linspace(-1.0, 1.0, 8 * 64).reshape(8, 64)
 
         def f(local):
@@ -117,7 +121,7 @@ def test_compressed_psum_wire_dtype_and_value():
                                    wire='f16')['g']
 
         fn = shard_map(f, mesh=mesh, in_specs=P('data'), out_specs=P(),
-                       check_rep=False)
+                       check_vma=False)
         got = fn(x)
         want = np.asarray(x, np.float32).mean(0)
         err = np.abs(np.asarray(got) - want).max()
@@ -140,12 +144,13 @@ def test_elastic_restore_different_dp():
     after losing nodes)."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np, tempfile
+        from repro.launch.mesh import make_mesh
         from repro.configs import OptimizerConfig, get_config, reduced_config
         from repro.launch.train import build_train_setup
         from repro.training import LoopConfig, run_training
         cfg = reduced_config(get_config('llama3.2-1b'))
         tmp = tempfile.mkdtemp()
-        mesh8 = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh8 = make_mesh((4, 2), ('data', 'model'))
         model, state, step, data, put, sh = build_train_setup(
             cfg, global_batch=8, seq_len=32, opt_cfg=OptimizerConfig(),
             steps_per_epoch=5, mesh=mesh8)
@@ -153,7 +158,7 @@ def test_elastic_restore_different_dp():
                      LoopConfig(total_steps=4, checkpoint_every=2,
                                 checkpoint_dir=tmp), put_batch=put)
         # 'lose half the nodes': rebuild on a (2,2) mesh and resume
-        mesh4 = jax.make_mesh((2, 2), ('data', 'model'))
+        mesh4 = make_mesh((2, 2), ('data', 'model'))
         model, state, step, data, put, sh = build_train_setup(
             cfg, global_batch=8, seq_len=32, opt_cfg=OptimizerConfig(),
             steps_per_epoch=5, mesh=mesh4)
@@ -172,6 +177,7 @@ def test_dryrun_entry_on_small_mesh():
     (full 512-device runs are exercised by launch/dryrun.py itself)."""
     out = run_py("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced_config
         import repro.configs.base as base
         import dataclasses
@@ -180,7 +186,7 @@ def test_dryrun_entry_on_small_mesh():
         base._REGISTRY['test-tiny'] = lambda: dataclasses.replace(
             cfg, name='test-tiny')
         from repro.launch.dryrun import lower_cell
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         rec, compiled = lower_cell('test-tiny', 'train_4k', mesh)
         assert rec['status'] == 'ok', rec
         assert rec['roofline']['bound_s'] > 0

@@ -21,6 +21,7 @@ ENV8 = {
 
 _BODY = """
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import OptimizerConfig, get_config, reduced_config
     from repro.launch.train import build_train_setup
     from repro.training.step import jit_train_step
@@ -86,7 +87,7 @@ def test_donation_parity_shardmap_8dev():
     """Explicit shard_map DP mode (bucketed sync) on 8 virtual devices:
     donation changes buffers only, never results."""
     body = _BODY.format(
-        mesh='jax.make_mesh((8, 1), ("data", "model"))',
+        mesh='make_mesh((8, 1), ("data", "model"))',
         dp_mode="shardmap", compression="bf16+bucketed")
     res = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
                          env=ENV8, capture_output=True, text=True,

@@ -308,13 +308,14 @@ def test_cross_replica_parity_8dev():
     (replicated, cotangents psum'd by shard_map AD)."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from functools import partial
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.batchnorm import bn_apply_stats, bn_batch_stats
         from repro.kernels.fused_bn import fused_bn_train
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         x = jax.random.normal(ks[0], (16, 4, 4, 12)) * 2.0 + 1.0
         cot = jax.random.normal(ks[1], x.shape)
@@ -336,7 +337,7 @@ def test_cross_replica_parity_8dev():
             sm = shard_map(local, mesh=mesh,
                            in_specs=(P("data"), P(), P(), P("data")),
                            out_specs=(P(), P(), P()),
-                           check_rep=False)
+                           check_vma=False)
             def loss(x, scale, bias):
                 l, m, v = sm(x, scale, bias, cot)
                 return l, (m, v)
@@ -366,12 +367,13 @@ def test_fused_composes_with_overlap_and_zero_8dev():
     bucketed step within tolerance."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import OptimizerConfig, get_config, \\
             reduced_config
         from repro.launch.train import build_train_setup
 
         cfg = reduced_config(get_config("resnet50"))
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
 
         def run(**kw):
             model, state, step, data, put, _ = build_train_setup(
@@ -413,12 +415,13 @@ def test_fused_step_matches_unfused_3steps_8dev(sync_bn):
     BN state within tolerance after 3 steps, plain and sync-BN."""
     out = run_py(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import OptimizerConfig, get_config, \\
             reduced_config
         from repro.launch.train import build_train_setup
 
         cfg = reduced_config(get_config("resnet50"))
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
 
         def run(fused):
             model, state, step, data, put, _ = build_train_setup(

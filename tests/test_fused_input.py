@@ -160,12 +160,13 @@ def test_fused_input_requires_conv_and_shardmap():
 
 _PARITY_BODY = """
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import (InputConfig, OptimizerConfig, get_config,
                                reduced_config)
     from repro.data.pipeline import DataPipeline
     from repro.launch.train import build_train_setup
     cfg = reduced_config(get_config('resnet50'))
-    mesh = jax.make_mesh((jax.device_count(), 1), ('data', 'model'))
+    mesh = make_mesh((jax.device_count(), 1), ('data', 'model'))
 
     def run(fused, workers):
         model, state, step, data, put, _ = build_train_setup(

@@ -209,15 +209,16 @@ def test_hier_primitives_bitwise_vs_flat_8dev():
         import functools
         import numpy as np
         import jax
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.distributed.bucketing import (
             make_hierarchy, hierarchical_psum, hierarchical_psum_scatter,
             hierarchical_all_gather)
         L = 512
         rng = np.random.default_rng(0)
         for shape in [(2, 4), (4, 2)]:
-            mesh = jax.make_mesh(shape, ('data', 'model'))
+            mesh = make_mesh(shape, ('data', 'model'))
             dp = ('data', 'model')
             hier = make_hierarchy(dp, dict(zip(dp, shape)), 1)
             N = hier.n_workers
@@ -227,7 +228,7 @@ def test_hier_primitives_bitwise_vs_flat_8dev():
 
                 @functools.partial(
                     shard_map, mesh=mesh, in_specs=P(dp),
-                    out_specs=P(dp), check_rep=False)
+                    out_specs=P(dp), check_vma=False)
                 def both(x):
                     b = x.reshape(-1)
                     flat = jax.lax.psum(b, dp)
@@ -278,6 +279,7 @@ _PARITY_BODY = """
     import os
     os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import (OptimizerConfig, ParallelConfig,
@@ -301,7 +303,7 @@ _PARITY_BODY = """
         for _ in range(2)]
 
     def run(shape, overlap, zero, hier_split):
-        mesh = jax.make_mesh(shape, ('data', 'model'))
+        mesh = make_mesh(shape, ('data', 'model'))
         DP = ('data', 'model')
         bshard = NamedSharding(mesh, P(DP))
         parallel = ParallelConfig(
@@ -410,6 +412,7 @@ def test_comm_plan_autotune_roundtrip_hlo_8dev(tmp_path):
         os.environ['XLA_FLAGS'] = \\
             '--xla_force_host_platform_device_count=8'
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.configs import (OptimizerConfig, get_config,
                                    reduced_config)
         from repro.distributed.comm_plan import resolve_comm_plan
@@ -421,7 +424,7 @@ def test_comm_plan_autotune_roundtrip_hlo_8dev(tmp_path):
             dp_axes=('data', 'model'), out_dir={str(tmp_path)!r})
         assert plan is not None, 'auto must find the tuned plan'
         # apply the plan the way launch/train.py main() does
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         dp_axes = (plan.dp_axes if plan.hier_split is not None
                    else ('data',))
         model, state, step, data, put, _ = build_train_setup(

@@ -32,6 +32,7 @@ from repro.distributed.bucketing import (
     segment_sq_partials,
     unpack,
 )
+from repro.launch.mesh import make_mesh
 from repro.optim import make_optimizer
 from repro.optim.lars import leaf_sq_norm, trust_from_sq
 from repro.optim.stream import make_stream_optimizer, trust_mask_segments
@@ -221,7 +222,7 @@ def test_stream_checks_require_bucketed_and_lars():
     sopt = make_stream_optimizer(OptimizerConfig(kind="lars"), 5, 32)
     cfg = TrainConfig(optimizer=OptimizerConfig(kind="lars"),
                       parallel=ParallelConfig(compression="bf16"))
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     with pytest.raises(ValueError, match="bucketed"):
         make_dp_shardmap_train_step(object(), sopt, cfg, mesh, ("data",))
 
@@ -309,13 +310,14 @@ _PARITY_BODY = """
     WIRE = @WIRE@
     EF = @EF@
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import OptimizerConfig, get_config, reduced_config
     from repro.distributed.bucketing import (plan_buckets,
                                              plan_ready_buckets,
                                              stream_to_shard_layout)
     from repro.launch.train import build_train_setup
     cfg = reduced_config(get_config('resnet50'))
-    mesh = jax.make_mesh((jax.device_count(), 1), ('data', 'model'))
+    mesh = make_mesh((jax.device_count(), 1), ('data', 'model'))
     N = jax.device_count()
     BB = 8192
     opt_cfg = OptimizerConfig(kind='lars', schedule='poly',

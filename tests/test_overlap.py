@@ -1,11 +1,11 @@
 """Backward-overlapped bucketed all-reduce (DESIGN.md §8).
 
-Single-process tests cover the staged-apply oracle (chained per-segment
-VJPs == monolithic AD, bitwise) and the ready-order BucketPlan
-(hypothesis round-trip). The step-level equivalence — overlapped ==
-non-overlapped bucketed, bitwise, plain + error-feedback — and the HLO
-interleaving proof run in subprocesses on virtual host meshes, like
-tests/test_bucketing.py.
+Single-process tests cover the ready-order BucketPlan (hypothesis
+round-trip). The staged-apply oracle (chained per-segment VJPs ==
+monolithic AD, bitwise) runs in a subprocess compiled without FMA; the
+step-level equivalence — overlapped == non-overlapped bucketed, bitwise,
+plain + error-feedback — and the HLO interleaving proof run in
+subprocesses on virtual host meshes, like tests/test_bucketing.py.
 """
 import os
 import subprocess
@@ -30,6 +30,14 @@ ENV8 = {
     "PYTHONPATH": os.path.join(os.path.dirname(__file__), "..", "src"),
 }
 ENV2 = {**ENV8, "XLA_FLAGS": "--xla_force_host_platform_device_count=2"}
+# One device, CPU code generated for AVX, which has no FMA. The staged
+# and monolithic backward passes are different XLA programs (the staged
+# one fuses per segment); on an FMA-capable host XLA's CPU backend
+# contracts multiply-add pairs into FMAs within each fusion, so the two
+# round differently although they run the same primitives. Without FMA
+# the bitwise comparison checks what it means to: same primitives, same
+# order.
+ENV_NO_FMA = {**ENV8, "XLA_FLAGS": "--xla_cpu_max_isa=AVX"}
 
 
 def run_py(body: str, env=ENV8, timeout=420) -> str:
@@ -41,20 +49,8 @@ def run_py(body: str, env=ENV8, timeout=420) -> str:
 
 
 # ---------------------------------------------------------------------------
-# staged apply == monolithic AD (single device, bitwise)
+# staged apply == monolithic AD (one device, no FMA, bitwise)
 # ---------------------------------------------------------------------------
-
-
-def _leaves_by_path(tree):
-    return {jax.tree_util.keystr(k): np.asarray(v)
-            for k, v in jax.tree_util.tree_leaves_with_path(tree)}
-
-
-def _assert_trees_bitwise(t1, t2, what=""):
-    d1, d2 = _leaves_by_path(t1), _leaves_by_path(t2)
-    assert set(d1) == set(d2), (what, set(d1) ^ set(d2))
-    for k in d1:
-        np.testing.assert_array_equal(d1[k], d2[k], err_msg=f"{what}{k}")
 
 
 @pytest.mark.parametrize("arch", ["resnet50", "llama3.2-1b"])
@@ -63,36 +59,52 @@ def test_staged_grads_bitwise_equal_monolithic(arch):
     reverse-mode AD of the composite loss — loss, grads, and (for BN
     models) the new model_state all bitwise-equal. llama3.2-1b ties its
     embeddings, so this also pins the carry-passthrough gradient path
-    for the shared table."""
-    from repro.configs import get_config, reduced_config
-    from repro.models import build_model, init_model_state
-    from repro.models.common import staged_value_and_grad
+    for the shared table. Compiled without FMA (``ENV_NO_FMA``)."""
+    out = run_py(f"""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.configs import get_config, reduced_config
+        from repro.models import build_model, init_model_state
+        from repro.models.common import staged_value_and_grad
 
-    cfg = reduced_config(get_config(arch))
-    model = build_model(cfg, compute_dtype=jnp.float32)
-    params, _ = model.init_params(jax.random.PRNGKey(0))
-    mstate = init_model_state(model)
-    if cfg.family == "conv":
-        batch = {"images": jax.random.normal(
-            jax.random.PRNGKey(1), (8, 32, 32, 3)),
-            "labels": jax.random.randint(
-                jax.random.PRNGKey(2), (8,), 0, cfg.num_classes)}
-    else:
-        assert cfg.tie_embeddings  # the interesting case
-        batch = {"tokens": jax.random.randint(
-            jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size),
-            "targets": jax.random.randint(
-                jax.random.PRNGKey(2), (4, 32), 0, cfg.vocab_size)}
+        def leaves_by_path(tree):
+            return {{jax.tree_util.keystr(k): np.asarray(v)
+                     for k, v in jax.tree_util.tree_leaves_with_path(tree)}}
 
-    (l1, (ns1, _)), g1 = jax.jit(jax.value_and_grad(
-        lambda p: model.loss_fn(p, mstate, batch, 0.1),
-        has_aux=True))(params)
-    l2, (ns2, _), g2 = jax.jit(lambda p: staged_value_and_grad(
-        model.loss_segments(p, mstate, batch, 0.1)))(params)
+        def assert_trees_bitwise(t1, t2, what=""):
+            d1, d2 = leaves_by_path(t1), leaves_by_path(t2)
+            assert set(d1) == set(d2), (what, set(d1) ^ set(d2))
+            for k in d1:
+                np.testing.assert_array_equal(d1[k], d2[k],
+                                              err_msg=f"{{what}}{{k}}")
 
-    assert float(l1) == float(l2)
-    _assert_trees_bitwise(g1, g2, "grad ")
-    _assert_trees_bitwise(ns1, ns2, "state ")
+        cfg = reduced_config(get_config({arch!r}))
+        model = build_model(cfg, compute_dtype=jnp.float32)
+        params, _ = model.init_params(jax.random.PRNGKey(0))
+        mstate = init_model_state(model)
+        if cfg.family == "conv":
+            batch = {{"images": jax.random.normal(
+                jax.random.PRNGKey(1), (8, 32, 32, 3)),
+                "labels": jax.random.randint(
+                    jax.random.PRNGKey(2), (8,), 0, cfg.num_classes)}}
+        else:
+            assert cfg.tie_embeddings  # the interesting case
+            batch = {{"tokens": jax.random.randint(
+                jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size),
+                "targets": jax.random.randint(
+                    jax.random.PRNGKey(2), (4, 32), 0, cfg.vocab_size)}}
+
+        (l1, (ns1, _)), g1 = jax.jit(jax.value_and_grad(
+            lambda p: model.loss_fn(p, mstate, batch, 0.1),
+            has_aux=True))(params)
+        l2, (ns2, _), g2 = jax.jit(lambda p: staged_value_and_grad(
+            model.loss_segments(p, mstate, batch, 0.1)))(params)
+
+        assert float(l1) == float(l2)
+        assert_trees_bitwise(g1, g2, "grad ")
+        assert_trees_bitwise(ns1, ns2, "state ")
+        print("STAGED_BITWISE_OK")
+    """, env=ENV_NO_FMA)
+    assert "STAGED_BITWISE_OK" in out
 
 
 def test_overlap_step_rejects_unstaged_model():
@@ -207,10 +219,11 @@ def test_ready_order_buckets_close_before_full_backward():
 
 _STEP_PAIR = """
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import OptimizerConfig, get_config, reduced_config
     from repro.launch.train import build_train_setup
     cfg = reduced_config(get_config('resnet50'))
-    mesh = jax.make_mesh((jax.device_count(), 1), ('data', 'model'))
+    mesh = make_mesh((jax.device_count(), 1), ('data', 'model'))
     def build(overlap):
         return build_train_setup(
             cfg, global_batch=8, seq_len=16, opt_cfg=OptimizerConfig(),
@@ -300,11 +313,12 @@ def test_overlap_trains_same_as_perleaf_trajectory():
     path), tight tolerance — whole-program compile differences only."""
     out = run_py(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import OptimizerConfig, get_config, \\
             reduced_config
         from repro.launch.train import build_train_setup
         cfg = reduced_config(get_config('resnet50'))
-        mesh = jax.make_mesh((2, 1), ('data', 'model'))
+        mesh = make_mesh((2, 1), ('data', 'model'))
         losses = {}
         for comp, overlap in (('bf16', False), ('bf16+bucketed', True)):
             model, state, step, data, put, _ = build_train_setup(

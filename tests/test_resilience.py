@@ -565,13 +565,14 @@ MODE_KW = {
 
 _PARITY_BODY = """
 import jax, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.configs import OptimizerConfig, get_config, reduced_config
 from repro.launch.train import build_train_setup
 from repro.resilience.sentinel import sentinel_controls
 
 cfg = reduced_config(get_config("resnet50"))
 opt = OptimizerConfig(kind="momentum_sgd", schedule="constant")
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 finals = []
 for sentinel in (False, True):
     _, state, step, data, put_batch, _ = build_train_setup(

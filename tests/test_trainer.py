@@ -4,6 +4,7 @@ Covers the paper's eval protocol — held-out split, pre-validation BN
 all-reduce, best-checkpoint retention, eval-state resume — plus the
 GSPMD/shard_map eval-logits parity the protocol guarantees.
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -184,6 +185,7 @@ def test_eval_logits_parity_gspmd_vs_shardmap():
     same init, uncompressed sync to isolate the BN path)."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import OptimizerConfig, get_config, \\
             reduced_config
         from repro.data import make_data
@@ -191,7 +193,7 @@ def test_eval_logits_parity_gspmd_vs_shardmap():
         from repro.launch.train import build_train_setup
         from repro.training.step import finalize_worker_bn_stats
         cfg = reduced_config(get_config('resnet50'))
-        mesh = jax.make_mesh((8, 1), ('data', 'model'))
+        mesh = make_mesh((8, 1), ('data', 'model'))
         logits = {}
         vb = make_data(cfg, ShapeConfig('val', 16, 16, 'train'), seed=0,
                        split='val').batch_at(0)
@@ -237,3 +239,33 @@ def test_cli_epoch_driven_both_modes():
         assert res.returncode == 0, f"STDERR:\n{res.stderr[-4000:]}"
         lines = [l for l in res.stdout.splitlines() if "val top1" in l]
         assert len(lines) == 2, res.stdout
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(tmp_path, from_env):
+    """``enable_compile_cache``: with JAX_COMPILATION_CACHE_DIR set the
+    cache is written there and nothing is set in code; without it the
+    cache goes to the one fixed directory inside the checkout."""
+    env = dict(SUBPROCESS_ENV_8DEV)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    res = subprocess.run([sys.executable, "-c", textwrap.dedent("""
+        import os, jax, jax.numpy as jnp
+        from repro.launch.train import COMPILE_CACHE_DIR, enable_compile_cache
+        d = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == d, d
+        print('DIR', d)
+        if 'JAX_COMPILATION_CACHE_DIR' in os.environ:
+            jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
+            jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()
+            print('ENTRIES', len(os.listdir(d)))
+    """)], env=env, capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0, res.stderr[-4000:]
+    lines = dict(l.split(" ", 1) for l in res.stdout.splitlines())
+    if from_env:
+        assert lines["DIR"] == str(tmp_path / "cache")
+        assert int(lines["ENTRIES"]) > 0
+    else:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert lines["DIR"] == os.path.join(repo, ".jax_cache")

@@ -28,6 +28,7 @@ from repro.distributed.bucketing import (
     shard_size,
     stream_to_shard_layout,
 )
+from repro.launch.mesh import make_mesh
 from repro.optim.rmsprop_warmup import _decay_mask
 from repro.optim.stream import decay_wd_stream, make_stream_optimizer
 
@@ -213,7 +214,7 @@ def test_zero_requires_stream_optimizer():
     cfg = TrainConfig(optimizer=OptimizerConfig(),
                       parallel=ParallelConfig(
                           compression="bf16+bucketed", zero_dp=True))
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     with pytest.raises(ValueError, match="stream optimizer"):
         make_dp_shardmap_train_step(object(), opt, cfg, mesh, ("data",))
 
@@ -267,13 +268,14 @@ _PARITY_HEADER = """
 
 _PARITY_BODY = """
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import OptimizerConfig, get_config, reduced_config
     from repro.distributed.bucketing import (plan_buckets,
                                              plan_ready_buckets,
                                              stream_to_shard_layout)
     from repro.launch.train import build_train_setup
     cfg = reduced_config(get_config('resnet50'))
-    mesh = jax.make_mesh((jax.device_count(), 1), ('data', 'model'))
+    mesh = make_mesh((jax.device_count(), 1), ('data', 'model'))
     N = jax.device_count()
     BB = 8192
 
@@ -366,6 +368,7 @@ def test_zero_bitwise_parity_two_dp_axes_8dev():
     all-reduce path on a (4, 2) mesh with dp_axes=('data', 'model')."""
     out = run_py(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import (OptimizerConfig, ParallelConfig,
                                    TrainConfig, get_config,
@@ -379,7 +382,7 @@ def test_zero_bitwise_parity_two_dp_axes_8dev():
         from repro.training.step import (make_dp_shardmap_train_step,
                                          replicate_model_state)
         cfg = reduced_config(get_config('resnet50'))
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         DP = ('data', 'model')
         N, BB = 8, 8192
         opt_cfg = OptimizerConfig()
@@ -450,6 +453,7 @@ def test_zero_bitwise_parity_two_dp_axes_8dev():
 def test_zero_checkpoint_crosses_layout_boundary_8dev(tmp_path):
     out = run_py(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np, tempfile, os
+        from repro.launch.mesh import make_mesh
         from repro.checkpoint.checkpointer import restore, save
         from repro.configs import (OptimizerConfig, get_config,
                                    reduced_config)
@@ -458,7 +462,7 @@ def test_zero_checkpoint_crosses_layout_boundary_8dev(tmp_path):
         from repro.optim.stream import (make_zero_restore_transform,
                                         param_key_tree)
         cfg = reduced_config(get_config('resnet50'))
-        mesh = jax.make_mesh((jax.device_count(), 1), ('data', 'model'))
+        mesh = make_mesh((jax.device_count(), 1), ('data', 'model'))
         N = jax.device_count()
         BB = 8192
 
@@ -527,12 +531,13 @@ def test_zero_hlo_reduce_scatter_no_allreduce():
     step's scatters must interleave with backward conv/dot compute."""
     out = run_py(textwrap.dedent("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.configs import (OptimizerConfig, get_config,
                                    reduced_config)
         from repro.launch.hlo_analysis import analyze_hlo, comm_report
         from repro.launch.train import build_train_setup
         cfg = reduced_config(get_config('resnet50'))
-        mesh = jax.make_mesh((jax.device_count(), 1), ('data', 'model'))
+        mesh = make_mesh((jax.device_count(), 1), ('data', 'model'))
         reports = {}
         for name, kw in (('bucketed', {}),
                          ('zero', dict(zero_dp=True)),
