@@ -39,6 +39,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from repro import telemetry  # noqa: E402
 from repro.configs import (  # noqa: E402
     OptimizerConfig,
     get_config,
@@ -89,18 +90,19 @@ def bench_mode(name: str, kw: dict, *, arch: str, global_batch: int,
     # the prefetch buffer (data-starved) vs everything else
     # (compute-bound). Per-step block_until_ready keeps the attribution
     # honest — async dispatch would hide compute under the next wait.
-    pipe = DataPipeline(data, start_step=0, depth=4,
-                        num_workers=data_workers, put=put)
-    try:
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            _, fed = next(pipe)
-            state, metrics = step(state, fed)
-            jax.block_until_ready(metrics["loss"])
-        fed_dt = (time.perf_counter() - t0) / iters
-        wait_s = pipe.wait_s_total / iters
-    finally:
-        pipe.close()
+    with telemetry.recording() as rec:
+        pipe = DataPipeline(data, start_step=0, depth=4,
+                            num_workers=data_workers, put=put)
+        try:
+            t0 = time.perf_counter()
+            for _ in range(iters):
+                _, fed = next(pipe)
+                state, metrics = step(state, fed)
+                jax.block_until_ready(metrics["loss"])
+            fed_dt = (time.perf_counter() - t0) / iters
+        finally:
+            pipe.close()
+    wait_s = rec.total_s("feed.wait") / iters
     row = {"ms_per_step": round(dt * 1e3, 3),
            "steps_per_sec": round(1.0 / dt, 3),
            "warmup_s": round(compile_s, 2),

@@ -28,22 +28,26 @@ exception object repeatedly accumulates traceback frames). ``close()``
 is race-free against concurrently blocked consumers and producers: both
 wait on the same condition variable and re-check the closed flag.
 
-Boundedness attribution (§15): ``next()`` accrues the time the consumer
-spent blocked waiting for the host stage into ``wait_s_total`` /
-``last_wait_s``. A compute-bound run shows ~zero wait (the buffer is
-always ahead); a data-starved run shows wait ≈ step-time gap. The
-trainer and step_bench surface this as ``data_wait_ms`` per step.
+Boundedness attribution (§15): the pipeline records three spans
+(``repro.telemetry``), each carrying the step it serves: ``feed.wait``,
+the consumer blocked in ``next()`` until the step's batch is in hand (a
+cold or late step's ``put`` included, the stage-ahead not);
+``feed.put``, each device ``put``; and ``feed.batch``, a worker's
+``batch_at`` plus transform. A compute-bound run shows ~zero wait (the
+buffer is always ahead); a data-starved run shows wait ≈ step-time gap.
+The trainer and step_bench read the ``feed.wait`` spans.
 """
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 from typing import Any, Callable, Dict, Iterator, Optional
 
 import numpy as np
 
 import jax
+
+from repro import telemetry
 
 
 class DataPipeline:
@@ -93,10 +97,6 @@ class DataPipeline:
         self._raised = False
         # device stage: (step, staged batch) in step order
         self._staged: deque = deque()
-        # attribution counters (host-wait only; device stage is async)
-        self.wait_s_total = 0.0
-        self.last_wait_s = 0.0
-        self.batches_delivered = 0
         self._threads = [
             threading.Thread(target=self._worker, daemon=True,
                              name=f"data-worker-{i}")
@@ -119,9 +119,10 @@ class DataPipeline:
                         break
                     self._cv.wait(timeout=0.1)
             try:
-                batch = self.source.batch_at(step)
-                if self.transform is not None:
-                    batch = self.transform(batch)
+                with telemetry.span("feed.batch", step):
+                    batch = self.source.batch_at(step)
+                    if self.transform is not None:
+                        batch = self.transform(batch)
             except BaseException as e:
                 with self._cv:
                     # keep the error of the smallest step: it is the one
@@ -176,36 +177,34 @@ class DataPipeline:
             host = self._host_get(nxt, block=False)
             if host is None:
                 return
-            self._staged.append((nxt, self._put(host)))
+            self._staged.append((nxt, self._staged_put(nxt, host)))
             last = nxt
+
+    def _staged_put(self, step: int, host):
+        with telemetry.span("feed.put", step):
+            return self._put(host)
 
     def __iter__(self) -> Iterator:
         return self
 
     def __next__(self):
         step = self._next_out
-        t0 = time.perf_counter()
+        with telemetry.span("feed.wait", step):
+            if self._put is not None:
+                if not (self._staged and self._staged[0][0] == step):
+                    # cold start / staging fell behind: block for it
+                    host = self._host_get(step, block=True)
+                    self._staged.append((step,
+                                         self._staged_put(step, host)))
+                _, batch = self._staged.popleft()
+            else:
+                batch = self._host_get(step, block=True)
+        self._next_out = step + 1
+        with self._cv:
+            self._cv.notify_all()
         if self._put is not None:
-            if not (self._staged and self._staged[0][0] == step):
-                # cold start / staging fell behind: block for this step
-                host = self._host_get(step, block=True)
-                self._staged.append((step, self._put(host)))
-            wait = time.perf_counter() - t0
-            _, batch = self._staged.popleft()
-            self._next_out = step + 1
-            with self._cv:
-                self._cv.notify_all()
             # stage ahead for future steps while compute runs
             self._stage_through(step + self._device_ahead)
-        else:
-            batch = self._host_get(step, block=True)
-            wait = time.perf_counter() - t0
-            self._next_out = step + 1
-            with self._cv:
-                self._cv.notify_all()
-        self.last_wait_s = wait
-        self.wait_s_total += wait
-        self.batches_delivered += 1
         return step, batch
 
     def close(self) -> None:

@@ -84,9 +84,10 @@ def _unpack_f16_kernel(x_ref, o_ref):
         x_ref[...].astype(jnp.uint32)).astype(o_ref.dtype)
 
 
-def cast_copy_2d(x, out_dtype, *, interpret, block_rows=BLOCK_ROWS):
+def cast_copy_2d(x, out_dtype, *, interpret, name, block_rows=BLOCK_ROWS):
     """x: (rows, 128) with rows a multiple of block_rows; returns x cast
-    to out_dtype, one fused pass."""
+    to out_dtype, one fused pass. ``name`` names the kernel's operation
+    in the compiled program and the device trace."""
     rows = x.shape[0]
     block_rows = min(block_rows, rows)
     assert rows % block_rows == 0, (rows, block_rows)
@@ -106,6 +107,7 @@ def cast_copy_2d(x, out_dtype, *, interpret, block_rows=BLOCK_ROWS):
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(x.shape, dst),
         interpret=interpret,
+        name=name,
     )(src)
     return (jax.lax.bitcast_convert_type(out, f16) if dst != out_dtype
             else out)
@@ -133,12 +135,14 @@ def pack_cast(flat, wire_dtype, *, interpret):
     whole tile grid for the kernel and trimmed from the result.
     """
     x2d, n = _to_lanes(flat)
-    out = cast_copy_2d(x2d, wire_dtype, interpret=interpret)
+    out = cast_copy_2d(x2d, wire_dtype, interpret=interpret,
+                       name="bucket_pack")
     return out.reshape(-1)[:n]
 
 
 def unpack_cast(flat, acc_dtype, *, interpret):
     """Inverse of pack_cast: wire-dtype stream -> accumulation dtype."""
     x2d, n = _to_lanes(flat)
-    out = cast_copy_2d(x2d, acc_dtype, interpret=interpret)
+    out = cast_copy_2d(x2d, acc_dtype, interpret=interpret,
+                       name="bucket_unpack")
     return out.reshape(-1)[:n]

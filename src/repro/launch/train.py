@@ -601,13 +601,16 @@ def main():
         if result.best:
             print(f"best: top1 {result.best['top1']:.4f} at epoch "
                   f"{result.best['epoch']}")
+        _print_stragglers(result.straggler_events)
         if args.log_json:
             with open(args.log_json, "w") as f:
                 json.dump({"history": result.history,
                            "epoch_history": result.epoch_history,
                            "best": result.best, "wall": wall,
                            "resumed_from": result.resumed_from,
-                           "events": result.events}, f)
+                           "events": result.events,
+                           "straggler_events": result.straggler_events,
+                           "telemetry": result.telemetry}, f)
         return
 
     # ---- legacy step-driven run (no validation) ----
@@ -625,12 +628,25 @@ def main():
     for h in result.history:
         print(f"  step {h['step']:5d} loss {h['loss']:.4f} "
               f"({h['time']*1e3:.0f} ms)")
-    if result.straggler_events:
-        print(f"straggler events: {len(result.straggler_events)}")
+    _print_stragglers(result.straggler_events)
     if args.log_json:
         with open(args.log_json, "w") as f:
             json.dump({"history": result.history, "wall": wall,
-                       "resumed_from": result.resumed_from}, f)
+                       "resumed_from": result.resumed_from,
+                       "straggler_events": result.straggler_events,
+                       "telemetry": result.telemetry}, f)
+
+
+def _print_stragglers(events) -> None:
+    """Each slow step with what its time went to (``Trainer.run``'s
+    straggler records)."""
+    if events:
+        print(f"straggler events: {len(events)}")
+    for e in events:
+        parts = ", ".join(f"{k} {v:.1f}" for k, v in e["spans_ms"].items())
+        print(f"  step {e['step']}: {e['time'] * 1e3:.1f} ms against a "
+              f"median {e['median'] * 1e3:.1f} ms ({parts} ms; "
+              f"{e['traces']} traces, {e['compiles']} compiles)")
 
 
 if __name__ == "__main__":

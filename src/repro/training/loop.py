@@ -23,12 +23,12 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.checkpoint import AsyncCheckpointer, list_checkpoints, restore
 from repro.checkpoint.checkpointer import BEST_DIR
 from repro.data.pipeline import DataPipeline
@@ -80,6 +80,8 @@ class TrainResult:
     # total time blocked on the input pipeline, and their ratio —
     # ~0 means compute-bound, ~1 means data-starved
     input_stats: Dict = dataclasses.field(default_factory=dict)
+    # the run's spans and counters (``telemetry.Recorder.summary``)
+    telemetry: Dict = dataclasses.field(default_factory=dict)
 
 
 class Trainer:
@@ -168,6 +170,10 @@ class Trainer:
         return meta
 
     def run(self) -> TrainResult:
+        with telemetry.recording() as rec:
+            return self._run(rec)
+
+    def _run(self, rec: telemetry.Recorder) -> TrainResult:
         cfg = self.cfg
         total_steps = cfg.epochs * cfg.steps_per_epoch
         ckpt = (AsyncCheckpointer(cfg.checkpoint_dir, cfg.keep_checkpoints)
@@ -227,16 +233,16 @@ class Trainer:
         history = []
         straggler_events = []
         step_times = []
-        data_wait_total = 0.0
-        wall_total = 0.0
         last_saved = start_step if resumed_from is not None else -1
         try:
             # anchor checkpoint: rollback must always have a target, even
             # when the divergence hits before the first periodic save
             if manager is not None and ckpt and not list_checkpoints(
                     cfg.checkpoint_dir):
-                ckpt.save(start_step, state,
-                          metadata=self._ckpt_metadata(eval_history, best))
+                with telemetry.span("ckpt.save", start_step):
+                    ckpt.save(start_step, state,
+                              metadata=self._ckpt_metadata(eval_history,
+                                                           best))
                 last_saved = start_step
 
             step = start_step
@@ -245,51 +251,55 @@ class Trainer:
             while step < total_steps:
                 if chaos is not None:
                     chaos.on_step_start(step)
-                t0 = time.perf_counter()  # includes data wait: that's what
-                try:                      # a straggling host looks like
-                    got_step, batch = next(prefetch)
-                except Exception as exc:
-                    # a dead input worker (the pipeline re-raises from
-                    # the consumer). With resilience: bounded pipeline
-                    # restarts at the current step; without: propagate
-                    # (the pre-existing error contract).
-                    if manager is None or data_retries_left <= 0:
-                        raise
-                    data_retries_left -= 1
-                    events.emit("data_restart", step=step,
-                                error=str(exc),
-                                retries_left=data_retries_left)
-                    prefetch.close()
-                    prefetch = make_pipeline(step)
-                    continue
-                data_wait = getattr(prefetch, "last_wait_s", 0.0)
-                if got_step != step:
-                    # a real error, not an assert: data/step misalignment
-                    # silently trains on wrong batches under `python -O`
-                    raise RuntimeError(
-                        f"prefetcher misalignment: got batch for step "
-                        f"{got_step}, expected {step}")
-                if self.resilience is not None:
-                    data_retries_left = self.resilience.data_retries
-                if manager is not None:
-                    state, metrics = self.train_step(
-                        state, batch, manager.controls(step))
-                else:
-                    state, metrics = self.train_step(state, batch)
-                loss = metrics.get("loss")
-                if loss is not None:
-                    loss = float(jax.device_get(loss))  # sync point
-                dt = time.perf_counter() - t0
-                data_wait_total += data_wait
-                wall_total += dt
+                # the step's span includes the wait on the feed: that
+                # is what a straggling host looks like
+                with telemetry.span("train.step", step) as st:
+                    try:
+                        got_step, batch = next(prefetch)
+                    except Exception as exc:
+                        # a dead input worker (the pipeline re-raises
+                        # from the consumer). With resilience: bounded
+                        # pipeline restarts at the current step;
+                        # without: propagate (the pre-existing error
+                        # contract).
+                        if manager is None or data_retries_left <= 0:
+                            raise
+                        data_retries_left -= 1
+                        events.emit("data_restart", step=step,
+                                    error=str(exc),
+                                    retries_left=data_retries_left)
+                        prefetch.close()
+                        prefetch = make_pipeline(step)
+                        continue
+                    if got_step != step:
+                        # a real error, not an assert: data/step
+                        # misalignment silently trains on wrong batches
+                        # under `python -O`
+                        raise RuntimeError(
+                            f"prefetcher misalignment: got batch for "
+                            f"step {got_step}, expected {step}")
+                    if self.resilience is not None:
+                        data_retries_left = self.resilience.data_retries
+                    with telemetry.span("train.dispatch", step):
+                        if manager is not None:
+                            state, metrics = self.train_step(
+                                state, batch, manager.controls(step))
+                        else:
+                            state, metrics = self.train_step(state, batch)
+                    loss = metrics.get("loss")
+                    if loss is not None:
+                        with telemetry.span("train.sync", step):
+                            loss = float(jax.device_get(loss))
+                dt = st.seconds
+                data_wait = st.children.get("feed.wait", 0) / 1e9
                 step_times.append(dt)
                 med = float(np.median(step_times[-50:]))
                 if len(step_times) > 5 and dt > cfg.deadline_factor * med:
-                    straggler_events.append({"step": step, "time": dt,
-                                             "median": med})
+                    slow = {"step": step, "time": dt, "median": med,
+                            **_breakdown(rec, st)}
+                    straggler_events.append(slow)
                     if events is not None:
-                        events.emit("straggler", step=step, time=dt,
-                                    median=med)
+                        events.emit("straggler", **slow)
 
                 # ---- recovery decision (before eval/save: a bad step
                 # must never be checkpointed or scored) ----
@@ -351,25 +361,28 @@ class Trainer:
                     epoch = done // cfg.steps_per_epoch
                     if (epoch % cfg.eval_every_epochs == 0
                             or epoch == cfg.epochs):
-                        rec = self.evaluate(state, epoch, done)
-                        eval_history.append(rec)
-                        top1 = rec.get("top1")
+                        with telemetry.span("eval", done):
+                            ev = self.evaluate(state, epoch, done)
+                        eval_history.append(ev)
+                        top1 = ev.get("top1")
                         if top1 is not None and (
                                 best is None or top1 > best["top1"]):
                             best = {"top1": top1, "epoch": epoch,
                                     "step": done}
                             if best_ckpt:
-                                best_ckpt.save(
-                                    done, state,
-                                    metadata=self._ckpt_metadata(
-                                        eval_history, best))
+                                with telemetry.span("ckpt.save", done):
+                                    best_ckpt.save(
+                                        done, state,
+                                        metadata=self._ckpt_metadata(
+                                            eval_history, best))
                 # eval before checkpoint so a resume replays from a
                 # manifest that already contains this epoch's record
                 if ckpt and cfg.checkpoint_every and not in_bad_streak \
                         and done % cfg.checkpoint_every == 0:
-                    ckpt.save(done, state,
-                              metadata=self._ckpt_metadata(eval_history,
-                                                           best))
+                    with telemetry.span("ckpt.save", done):
+                        ckpt.save(done, state,
+                                  metadata=self._ckpt_metadata(
+                                      eval_history, best))
                     last_saved = done
                     if chaos is not None \
                             and chaos.has_pending_ckpt_fault(done):
@@ -381,9 +394,11 @@ class Trainer:
             # saved async then immediately re-saved blocking, rmtree-ing
             # the fresh directory)
             if ckpt and last_saved != total_steps:
-                ckpt.save(total_steps, state,
-                          metadata=self._ckpt_metadata(eval_history, best),
-                          block=True)
+                with telemetry.span("ckpt.save", total_steps):
+                    ckpt.save(total_steps, state,
+                              metadata=self._ckpt_metadata(eval_history,
+                                                           best),
+                              block=True)
         finally:
             prefetch.close()
             if best_ckpt:
@@ -392,18 +407,32 @@ class Trainer:
                 ckpt.wait()
             if events is not None:
                 events.close()
+        wall_s = rec.total_s("train.step")
+        wait_s = rec.total_s("feed.wait")
         input_stats = {
-            "wall_s": wall_total,
-            "data_wait_s": data_wait_total,
-            "data_starved_frac": (data_wait_total / wall_total
-                                  if wall_total > 0 else 0.0),
+            "wall_s": wall_s,
+            "data_wait_s": wait_s,
+            "data_starved_frac": wait_s / wall_s if wall_s > 0 else 0.0,
         }
         return TrainResult(state=state, history=history,
                            epoch_history=eval_history,
                            straggler_events=straggler_events,
                            resumed_from=resumed_from, best=best,
                            events=list(events.records) if events else [],
-                           input_stats=input_stats)
+                           input_stats=input_stats,
+                           telemetry=rec.summary())
+
+
+def _breakdown(rec: telemetry.Recorder, st) -> Dict:
+    """What a step's time went to: the milliseconds of each span inside
+    its ``train.step`` span (``self`` for the rest), and the retraces and
+    compiles counted in it."""
+    spans_ms = {k: v / 1e6 for k, v in st.children.items()}
+    spans_ms["self"] = st.seconds * 1e3 - sum(spans_ms.values())
+    counters = rec.step_counters.get(st.step, {})
+    return {"spans_ms": spans_ms,
+            **{k: counters.get(k, 0) for k in ("traces", "compiles",
+                                               "compile_s")}}
 
 
 # ---------------------------------------------------------------------------
@@ -428,6 +457,7 @@ class LoopResult:
     history: list
     straggler_events: list
     resumed_from: Optional[int]
+    telemetry: Dict = dataclasses.field(default_factory=dict)
 
 
 def run_training(
@@ -454,4 +484,5 @@ def run_training(
                      state_shardings=state_shardings).run()
     return LoopResult(state=result.state, history=result.history,
                       straggler_events=result.straggler_events,
-                      resumed_from=result.resumed_from)
+                      resumed_from=result.resumed_from,
+                      telemetry=result.telemetry)

@@ -699,16 +699,21 @@ def make_dp_shardmap_train_step(model, optimizer: Optimizer,
             batch = input_transform(batch)
         # mstate leaves carry a leading per-worker dim (1, ...) locally
         local_mstate = jax.tree.map(lambda x: x[0], mstate)
-        (loss, (new_mstate, metrics)), grads = jax.value_and_grad(
-            model.loss_fn, has_aux=True)(params, local_mstate, batch,
-                                         train_cfg.label_smoothing)
+        # named scopes group the step's operations in xprof's
+        # framework-op view
+        with jax.named_scope("fwd"):
+            (loss, (new_mstate, metrics)), grads = jax.value_and_grad(
+                model.loss_fn, has_aux=True)(params, local_mstate, batch,
+                                             train_cfg.label_smoothing)
         # ---- the paper's technique: fp16/bf16 compressed all-reduce ----
         local_residual = (jax.tree.map(lambda x: x[0], residual)
                           if use_ef else None)
-        grads, new_residual, sq_norm = sync_grads(grads, local_residual)
-        metrics = _pmean_metrics(metrics, dp_axes)
-        new_params, new_opt, opt_metrics = optimizer.update(
-            params, grads, opt)
+        with jax.named_scope("grad_sync"):
+            grads, new_residual, sq_norm = sync_grads(grads, local_residual)
+            metrics = _pmean_metrics(metrics, dp_axes)
+        with jax.named_scope("update"):
+            new_params, new_opt, opt_metrics = optimizer.update(
+                params, grads, opt)
         metrics.update(opt_metrics)
         metrics["grad_norm"] = (jnp.sqrt(sq_norm) if sq_norm is not None
                                 else global_norm(grads))

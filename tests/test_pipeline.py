@@ -11,6 +11,7 @@ import tracemalloc
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.data.pipeline import DataPipeline, StepStampSource
 from repro.data.synthetic import Prefetcher, SyntheticImageData
 
@@ -232,17 +233,18 @@ def test_device_staging_never_swallows_error_attribution():
 
 def test_wait_attribution_counters():
     src = CountingSource(delays={3: 0.25})
-    pipe = DataPipeline(src, num_workers=1, depth=2)
-    try:
-        waits = []
-        for _ in range(5):
-            next(pipe)
-            waits.append(pipe.last_wait_s)
-        assert pipe.batches_delivered == 5
-        assert pipe.wait_s_total == pytest.approx(sum(waits))
-        assert max(waits) >= 0.1  # the slow step shows up as wait
-    finally:
-        pipe.close()
+    with telemetry.recording() as rec:
+        pipe = DataPipeline(src, num_workers=1, depth=2)
+        try:
+            for _ in range(5):
+                next(pipe)
+        finally:
+            pipe.close()
+    spans = rec.named("feed.wait")
+    waits = [s.seconds for s in spans]
+    assert [s.step for s in spans] == list(range(5))  # 5 deliveries
+    assert rec.total_s("feed.wait") == pytest.approx(sum(waits))
+    assert waits[3] >= 0.1  # the slow step shows up as wait
 
 
 def test_step_stamp_source():

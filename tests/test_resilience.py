@@ -519,7 +519,6 @@ def test_step_misalignment_raises_runtime_error(sent, monkeypatch):
         def __init__(self, source, start_step=0, depth=2, transform=None,
                      num_workers=1, put=None, device_ahead=1):
             self._step = start_step
-            self.last_wait_s = 0.0
 
         def __next__(self):
             return self._step + 1, None  # off by one

@@ -14,6 +14,7 @@ this file loads no TPU library; where it cannot be described, every test
 here skips.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -106,10 +107,13 @@ def test_fused_update(one_chip, compiled_kernels, array_wd):
 
 @pytest.mark.parametrize("wire", [jnp.bfloat16, jnp.float16])
 def test_bucket_pack_unpack_cast(one_chip, compiled_kernels, wire):
-    _compile(lambda x: ops.pack_cast(x, wire),
-             _spec(one_chip, (BUCKET_ELEMS,), jnp.float32))
-    _compile(lambda x: ops.unpack_cast(x, jnp.float32),
-             _spec(one_chip, (BUCKET_ELEMS,), wire))
+    pack = _compile(lambda x: ops.pack_cast(x, wire),
+                    _spec(one_chip, (BUCKET_ELEMS,), jnp.float32))
+    unpack = _compile(lambda x: ops.unpack_cast(x, jnp.float32),
+                      _spec(one_chip, (BUCKET_ELEMS,), wire))
+    # the kernels' operations carry their names into the device trace
+    assert re.search(r"%bucket_pack[.\d]* = \S+ custom-call\(", pack)
+    assert re.search(r"%bucket_unpack[.\d]* = \S+ custom-call\(", unpack)
 
 
 @pytest.mark.parametrize("train", [True, False])
