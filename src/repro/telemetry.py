@@ -34,11 +34,12 @@ The spans and who reads them (PERF.md section 3):
 =================  ==========================================  =============
 span / counter     where                                       reader
 =================  ==========================================  =============
-``train.step``     ``Trainer.run``: next batch .. loss sync    step time,
-                                                               stragglers
+``train.step``     ``Trainer.run``: next batch .. the previous step time,
+                   step's loss sync                            stragglers
 ``train.dispatch`` ``Trainer.run``: the jitted step call       ``dispatch_ms``,
                                                                ``idle_dispatch_pct``
-``train.sync``     ``Trainer.run``: the loss to the host       stragglers
+``train.sync``     ``Trainer.run``: a step's loss to the host, stragglers
+                   inside the next step's ``train.step``
 ``feed.wait``      ``DataPipeline.__next__`` until the batch   ``input_wait_pct``
                    is in hand
 ``feed.put``       ``DataPipeline``: each device ``put``       stragglers
@@ -49,6 +50,8 @@ span / counter     where                                       reader
 ``traces``,        the ``jax.monitoring`` listener             stragglers
 ``compiles``,
 ``compile_s``
+``train.overlap``  ``Trainer.run``: a step dispatched while    stragglers,
+                   the previous step's loss was unread         ``--log-json``
 =================  ==========================================  =============
 """
 from __future__ import annotations

@@ -13,8 +13,12 @@ retention, and eval-state resume.
 
 The hot loop stays deliberately thin — all heavy lifting is in the
 jitted steps — so at 1000+ nodes the host-side critical path is just
-`device_put` of the next batch (prefetched) and dispatch. Validation
-runs only at epoch boundaries, off the steady-state path.
+`device_put` of the next batch (prefetched) and dispatch. One step is
+kept in flight: step n is dispatched before step n-1's loss is read, so
+the host's call of step n (and its feed) overlaps the device running
+step n-1. A ``RecoveryManager`` must judge step n before step n+1 runs,
+so with one the loss is read right after each dispatch. Validation runs
+only at epoch boundaries, off the steady-state path.
 
 ``run_training`` remains as the legacy step-driven API (one epoch, no
 eval) layered on the same loop.
@@ -248,6 +252,21 @@ class Trainer:
             step = start_step
             data_retries_left = (self.resilience.data_retries
                                  if self.resilience else 0)
+            # without a manager, the history entry of the step whose
+            # loss is still on the device: read after the next dispatch
+            pending: Optional[Dict] = None
+
+            def read_loss(loss, at):
+                if loss is None:
+                    return None
+                with telemetry.span("train.sync", at):
+                    return float(jax.device_get(loss))
+
+            def log(entry):
+                s = entry["step"]
+                if s % cfg.log_every == 0 or s == total_steps - 1:
+                    history.append(entry)
+
             while step < total_steps:
                 if chaos is not None:
                     chaos.on_step_start(step)
@@ -287,9 +306,16 @@ class Trainer:
                         else:
                             state, metrics = self.train_step(state, batch)
                     loss = metrics.get("loss")
-                    if loss is not None:
-                        with telemetry.span("train.sync", step):
-                            loss = float(jax.device_get(loss))
+                    if manager is not None:
+                        loss = read_loss(loss, step)
+                    elif pending is not None:
+                        # step n is queued behind step n-1 on the
+                        # device; now wait for n-1
+                        if pending["loss"] is not None:
+                            telemetry.count("train.overlap")
+                        pending["loss"] = read_loss(pending["loss"],
+                                                    pending["step"])
+                        log(pending)
                 dt = st.seconds
                 data_wait = st.children.get("feed.wait", 0) / 1e9
                 step_times.append(dt)
@@ -350,35 +376,48 @@ class Trainer:
                 in_bad_streak = (manager is not None
                                  and manager.consecutive_bad > 0)
 
-                if step % cfg.log_every == 0 or step == total_steps - 1:
-                    history.append({"step": step, "loss": loss,
-                                    "time": dt, "data_wait": data_wait})
+                entry = {"step": step, "loss": loss, "time": dt,
+                         "data_wait": data_wait}
+                if manager is not None:
+                    log(entry)
+                else:
+                    pending = entry
 
                 done = step + 1
+                epoch = done // cfg.steps_per_epoch
+                eval_due = (self._eval_enabled() and not in_bad_streak
+                            and done % cfg.steps_per_epoch == 0
+                            and (epoch % cfg.eval_every_epochs == 0
+                                 or epoch == cfg.epochs))
+                save_due = bool(ckpt and cfg.checkpoint_every
+                                and not in_bad_streak
+                                and done % cfg.checkpoint_every == 0)
+                if pending is not None and (eval_due or save_due
+                                            or done == total_steps):
+                    # the history is whole, in step order, before an
+                    # eval, a save or the end
+                    pending["loss"] = read_loss(pending["loss"], step)
+                    log(pending)
+                    pending = None
                 # ---- epoch boundary: the paper's eval path ----
-                if self._eval_enabled() and not in_bad_streak \
-                        and done % cfg.steps_per_epoch == 0:
-                    epoch = done // cfg.steps_per_epoch
-                    if (epoch % cfg.eval_every_epochs == 0
-                            or epoch == cfg.epochs):
-                        with telemetry.span("eval", done):
-                            ev = self.evaluate(state, epoch, done)
-                        eval_history.append(ev)
-                        top1 = ev.get("top1")
-                        if top1 is not None and (
-                                best is None or top1 > best["top1"]):
-                            best = {"top1": top1, "epoch": epoch,
-                                    "step": done}
-                            if best_ckpt:
-                                with telemetry.span("ckpt.save", done):
-                                    best_ckpt.save(
-                                        done, state,
-                                        metadata=self._ckpt_metadata(
-                                            eval_history, best))
+                if eval_due:
+                    with telemetry.span("eval", done):
+                        ev = self.evaluate(state, epoch, done)
+                    eval_history.append(ev)
+                    top1 = ev.get("top1")
+                    if top1 is not None and (
+                            best is None or top1 > best["top1"]):
+                        best = {"top1": top1, "epoch": epoch,
+                                "step": done}
+                        if best_ckpt:
+                            with telemetry.span("ckpt.save", done):
+                                best_ckpt.save(
+                                    done, state,
+                                    metadata=self._ckpt_metadata(
+                                        eval_history, best))
                 # eval before checkpoint so a resume replays from a
                 # manifest that already contains this epoch's record
-                if ckpt and cfg.checkpoint_every and not in_bad_streak \
-                        and done % cfg.checkpoint_every == 0:
+                if save_due:
                     with telemetry.span("ckpt.save", done):
                         ckpt.save(done, state,
                                   metadata=self._ckpt_metadata(
@@ -425,14 +464,16 @@ class Trainer:
 
 def _breakdown(rec: telemetry.Recorder, st) -> Dict:
     """What a step's time went to: the milliseconds of each span inside
-    its ``train.step`` span (``self`` for the rest), and the retraces and
-    compiles counted in it."""
+    its ``train.step`` span (``self`` for the rest; its ``train.sync``
+    waited for the previous step where ``train.overlap`` is 1), and the
+    retraces and compiles counted in it."""
     spans_ms = {k: v / 1e6 for k, v in st.children.items()}
     spans_ms["self"] = st.seconds * 1e3 - sum(spans_ms.values())
     counters = rec.step_counters.get(st.step, {})
     return {"spans_ms": spans_ms,
             **{k: counters.get(k, 0) for k in ("traces", "compiles",
-                                               "compile_s")}}
+                                               "compile_s",
+                                               "train.overlap")}}
 
 
 # ---------------------------------------------------------------------------

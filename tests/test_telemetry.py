@@ -141,13 +141,25 @@ def test_trainer_spans_every_step():
     by_step = {}
     for s in rec.spans:
         by_step.setdefault(s.step, []).append(s)
+    steps_by_k = {s.step: s for s in rec.named("train.step")}
+
+    def within(inner, outer):
+        assert inner.parent == "train.step"
+        assert outer.t0_ns <= inner.t0_ns <= inner.t1_ns <= outer.t1_ns
+
     for k in range(steps):
         main = {s.name: s for s in by_step[k] if s.name != "feed.batch"}
-        outer = main["train.step"]
-        for name in ("feed.wait", "train.dispatch", "train.sync"):
-            inner = main[name]
-            assert inner.parent == "train.step"
-            assert outer.t0_ns <= inner.t0_ns <= inner.t1_ns <= outer.t1_ns
+        for name in ("feed.wait", "train.dispatch"):
+            within(main[name], main["train.step"])
+        # step k's loss is read inside step k+1, once that is dispatched;
+        # the last step's after its span
+        sync = main["train.sync"]
+        if k + 1 < steps:
+            within(sync, steps_by_k[k + 1])
+            assert sync.t0_ns >= rec.named("train.dispatch")[k + 1].t1_ns
+        else:
+            assert sync.parent is None
+            assert sync.t0_ns >= main["train.step"].t1_ns
         assert sum(s.name == "feed.batch" for s in by_step[k]) == 1
         assert sum(s.name == "train.step" for s in by_step[k]) == 1
     # the feed's workers made the batches, off the main thread
@@ -170,7 +182,8 @@ def test_recompile_is_counted_in_its_step_and_the_straggler_record():
     assert rec.step_counters[wide]["traces"] >= 1
     assert rec.step_counters[wide]["compiles"] >= 1
     # steady steps (after the first, which compiled) compile nothing
-    assert all(k in (0, wide) for k in rec.step_counters)
+    assert all(k in (0, wide) for k, c in rec.step_counters.items()
+               if c.get("traces") or c.get("compiles"))
     slow = {e["step"]: e for e in res.straggler_events}
     assert wide in slow, res.straggler_events
     got = slow[wide]
